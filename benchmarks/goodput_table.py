@@ -328,6 +328,8 @@ def main() -> None:
     ap.add_argument("--trace-dir", default="traces",
                     help="directory for --trace artifacts")
     args = ap.parse_args()
+    from repro.core import runtime
+    runtime.init_compile_cache()
 
     tracer = None
     if args.trace:

@@ -380,6 +380,8 @@ def main() -> None:
                          "BENCH_<pr>.json; reports MFU deltas vs the "
                          "previous ledger")
     args = ap.parse_args()
+    from repro.core import runtime
+    runtime.init_compile_cache()
 
     fresh = measure()
     print("name,us_per_call,derived")

@@ -57,6 +57,8 @@ def main() -> None:
                          "(nonzero exit on NaN/zero denominators)")
     ap.add_argument("--device", default="tpu-v5e")
     args = ap.parse_args()
+    from repro.core import runtime
+    runtime.init_compile_cache()
 
     print("name,us_per_call,derived")
     if args.smoke:

@@ -84,9 +84,13 @@ def backend_smoke() -> int:
     for name in backends.list_backends():
         eng = VisionEngine(params, backend=name, batch_size=4, warmup=False)
         res = eng.serve(list(np.asarray(x)))
-        ok = len(res) == 8 and all(r.latency_s > 0 for r in res)
-        failed |= not ok
         s = eng.stats()
+        # a faulted step sheds its batch instead of raising, so a shed
+        # here is a failure even when the caller got an answer back
+        ok = (len(res) == 8 and all(r is not None and r.latency_s > 0
+                                    for r in res)
+              and s["shed"] == 0 and s["accounted"])
+        failed |= not ok
         print(f"smoke/engine_{name},{s['latency_mean_ms']*1e3:.2f},"
               f"served={s['n']} {'OK' if ok else 'FAIL'}")
     print(f"smoke/result,,{'FAIL' if failed else 'OK'}")
@@ -101,6 +105,8 @@ def main() -> None:
                     help="backend parity smoke (tiny batch, no training); "
                          "exits nonzero on parity failure")
     args = ap.parse_args()
+    from repro.core import runtime
+    runtime.init_compile_cache()
 
     if args.backends:
         sys.exit(backend_smoke())

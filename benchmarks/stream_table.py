@@ -48,12 +48,6 @@ a `metrics.prom` Prometheus exposition, every span is reconciled against
 the pipeline AND engine ledgers, and (with `--smoke`) traced FPS must hold
 >= 95% of the untraced rate measured in the same process.
 
-`--real-device` flips the process-wide interpret switch off
-(`backends.set_interpret(False)`): every Pallas kernel compiles for the
-attached accelerator instead of running the CPU interpreter.  The CPU CI
-lanes keep the interpret default; the flag is for bench runs on real
-hardware.
-
     PYTHONPATH=src python -m benchmarks.stream_table --frames 100 --sweep
     PYTHONPATH=src python -m benchmarks.stream_table --frames 30 --smoke
 """
@@ -710,14 +704,9 @@ def main() -> None:
                          "cache hit rate, and (with --smoke) the "
                          "word-exactness / parity / hit-rate / speedup "
                          "gates")
-    ap.add_argument("--real-device", action="store_true",
-                    help="compile Pallas kernels for the attached "
-                         "accelerator instead of the CPU interpreter "
-                         "(backends.set_interpret(False), process-wide)")
     args = ap.parse_args()
-    if args.real_device:
-        from repro.core import backends as B
-        B.set_interpret(False)
+    from repro.core import runtime
+    runtime.init_compile_cache()
 
     print("name,us_per_call,derived")
     rows, failures = run(frames=args.frames, fps=args.fps,

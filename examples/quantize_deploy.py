@@ -9,7 +9,7 @@ import jax
 import numpy as np
 
 from repro.configs.base import get_config
-from repro.core import ptq
+from repro.core import ptq, runtime
 from repro.runtime.trainer import Trainer, TrainerConfig
 from repro.serving.engine import Engine, Request
 
@@ -19,6 +19,7 @@ def main():
     ap.add_argument("--arch", default="granite-3-2b")
     ap.add_argument("--train-steps", type=int, default=30)
     args = ap.parse_args()
+    runtime.init_compile_cache()
 
     cfg = get_config(args.arch).smoke()
     print(f"== 1. train {args.arch} (reduced) for {args.train_steps} steps ==")

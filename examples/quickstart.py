@@ -12,7 +12,7 @@ import argparse
 
 import jax.numpy as jnp
 
-from repro.core import backends, deploy, smallnet
+from repro.core import backends, deploy, runtime, smallnet
 from repro.data import synth_mnist
 from repro.launch.mesh import make_serving_mesh
 from repro.serving.router import ReplicaRouter
@@ -27,6 +27,7 @@ def main():
                     choices=backends.list_backends(),
                     help="inference substrate for the serving demo")
     args = ap.parse_args()
+    runtime.init_compile_cache()
 
     print("== 1. train float smallNet (paper §III-A: Adam, batch 64) ==")
     res = deploy.train_smallnet(n_train=args.n_train, n_test=1500,

@@ -13,6 +13,7 @@ import jax
 import numpy as np
 
 from repro.configs.base import get_config
+from repro.core import runtime
 from repro.models import model as M
 from repro.serving.engine import Engine, Request
 
@@ -24,6 +25,7 @@ def main():
     ap.add_argument("--batch", type=int, default=4)
     ap.add_argument("--max-new", type=int, default=8)
     args = ap.parse_args()
+    runtime.init_compile_cache()
 
     cfg = get_config(args.arch).smoke()
     model = M.build(cfg)

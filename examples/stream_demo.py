@@ -25,7 +25,7 @@ import argparse
 
 import jax
 
-from repro.core import backends, deploy, smallnet
+from repro.core import backends, deploy, runtime, smallnet
 from repro.obs import recorder as obs_recorder
 from repro.obs import trace as obs_trace
 from repro.serving.vision_engine import VisionEngine
@@ -62,6 +62,7 @@ def main():
     ap.add_argument("--trace-dir", default=".",
                     help="directory for the --trace dump")
     args = ap.parse_args()
+    runtime.init_compile_cache()
 
     tracer = None
     if args.trace:

@@ -13,6 +13,7 @@ import dataclasses
 import jax.numpy as jnp
 
 from repro.configs.base import get_config
+from repro.core import runtime
 from repro.runtime.trainer import Trainer, TrainerConfig
 
 
@@ -25,6 +26,7 @@ def main():
     ap.add_argument("--batch", type=int, default=8)
     ap.add_argument("--ckpt-dir", default="/tmp/repro_ckpt")
     args = ap.parse_args()
+    runtime.init_compile_cache()
 
     cfg = get_config(args.arch).smoke()
     if args.preset == "100m":

@@ -14,21 +14,22 @@ from __future__ import annotations
 from typing import Any, Callable
 
 import jax
+from jax.extend import core as jex_core
 
 
 def _subjaxprs(params: dict):
     """Sub-jaxprs hiding in an eqn's params (pjit jaxpr=..., scan/cond
     branches=[...], custom_* call_jaxpr=...)."""
     for v in params.values():
-        if isinstance(v, jax.core.ClosedJaxpr):
+        if isinstance(v, jex_core.ClosedJaxpr):
             yield v.jaxpr
-        elif isinstance(v, jax.core.Jaxpr):
+        elif isinstance(v, jex_core.Jaxpr):
             yield v
         elif isinstance(v, (list, tuple)):
             for item in v:
-                if isinstance(item, jax.core.ClosedJaxpr):
+                if isinstance(item, jex_core.ClosedJaxpr):
                     yield item.jaxpr
-                elif isinstance(item, jax.core.Jaxpr):
+                elif isinstance(item, jex_core.Jaxpr):
                     yield item
 
 

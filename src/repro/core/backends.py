@@ -62,7 +62,7 @@ from repro.kernels.maxpool2d.ops import maxpool2d
 from repro.kernels.quant_matmul.ops import fixed_dense, quant_matmul
 from repro.kernels.sigmoid_pla.ops import sigmoid_pla
 
-# the process-wide interpret/real-device switch, re-exported here because
+# the process-wide interpret switch, re-exported here because
 # the backend registry is where callers already look for substrate knobs
 set_interpret = runtime.set_interpret
 interpret_default = runtime.interpret_default
@@ -72,13 +72,25 @@ interpret_default = runtime.interpret_default
 # Shared float primitives (the XLA reference datapath)
 # ---------------------------------------------------------------------------
 
+# The float path is the f32 reference the other substrates are held to, so
+# its MACs are pinned to full f32: at default precision a TPU rounds f32
+# conv/matmul operands to bf16, and the reference would drift from itself
+# across platforms by more than the parity tolerances.
+_F32 = jax.lax.Precision.HIGHEST
+
+
 def conv_same_2x2(x: jnp.ndarray, w: jnp.ndarray, b: jnp.ndarray) -> jnp.ndarray:
     """2x2 SAME conv, NHWC/HWIO. Keras pads SAME for even kernels as
     (0 before, 1 after) on each spatial dim."""
     y = jax.lax.conv_general_dilated(
         x, w, window_strides=(1, 1), padding=((0, 1), (0, 1)),
-        dimension_numbers=("NHWC", "HWIO", "NHWC"))
+        dimension_numbers=("NHWC", "HWIO", "NHWC"), precision=_F32)
     return y + b
+
+
+def dense_f32(x: jnp.ndarray, w: jnp.ndarray, b: jnp.ndarray) -> jnp.ndarray:
+    """Float fully-connected layer, pre-activation: x @ w + b."""
+    return jnp.dot(x, w, precision=_F32) + b
 
 
 def maxpool_2x2(x: jnp.ndarray) -> jnp.ndarray:
@@ -141,7 +153,7 @@ class Backend:
         return maxpool_2x2(x)
 
     def dense(self, x, w, b):
-        return x @ w + b
+        return dense_f32(x, w, b)
 
     def sigmoid(self, x):
         return self.sigmoid_fn(x)
@@ -259,10 +271,9 @@ class PallasBackend(Backend):
     "ref") or "plan" (the PLAN piecewise-linear epilogue, matches "plan");
     the standalone activation after the dense layer uses the matching
     implementation (sigmoid_pla VPU kernel for "plan").
-    `interpret=None` follows the process-wide `core.runtime` switch
-    (interpreter on CPU hosts by default; `runtime.set_interpret(False)` —
-    or a benchmark's `--real-device` — compiles for real TPUs); an explicit
-    bool pins this instance regardless of the switch.
+    `interpret=None` follows the process-wide `core.runtime` default
+    (the interpreter on a CPU backend, compiled kernels on a TPU); an
+    explicit bool pins this instance regardless of the default.
     """
     name: str = "pallas"
     activation: str = "sigmoid"
@@ -453,7 +464,7 @@ class Int8Backend(Backend):
 
     def dense(self, x, w, b):
         if not isinstance(w, ptq.QuantTensor):           # float fallback
-            return x @ w + b
+            return dense_f32(x, w, b)
         xq = ptq.quantize(x, dataclasses.replace(self.qcfg, per_channel=False))
         y = quant_matmul(xq.q, w.q, xq.scale.reshape(()),
                             w.scale.reshape(-1), interpret=self.interpret)

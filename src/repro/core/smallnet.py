@@ -62,6 +62,17 @@ def seeded_params(seed: int = 0, noise: float = 0.1) -> dict:
         for l, k in zip(leaves, keys)])
 
 
+def params_from_words(words: dict,
+                      cfg: fxp.FixedPointConfig = fxp.Q16_16) -> dict:
+    """Float params that quantize back to exactly `words` (a Qm.n int
+    pytree whose leaves are arrays or nested lists, e.g. the golden inputs
+    stored in tests/golden/): each leaf is words / 2**frac_bits, exact in
+    float32 for |word| < 2**24."""
+    return jax.tree_util.tree_map(
+        lambda w: fxp.from_fixed(jnp.asarray(w, jnp.int32), cfg), words,
+        is_leaf=lambda x: isinstance(x, list))
+
+
 def _constrain_batch(x: jnp.ndarray) -> jnp.ndarray:
     """Pin dim 0 to the "batch" logical axis, replicate the rest.
 

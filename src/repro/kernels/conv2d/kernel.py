@@ -29,6 +29,7 @@ from jax.experimental import pallas as pl
 from repro.core.fixed_point import sigmoid_plan_f32
 
 _ACTIVATIONS = (None, "sigmoid", "plan")
+_F32 = jax.lax.Precision.HIGHEST    # full-f32 MACs, like the XLA reference
 
 
 def _conv_kernel(x_ref, w_ref, b_ref, o_ref, *, kh: int, kw: int,
@@ -46,6 +47,7 @@ def _conv_kernel(x_ref, w_ref, b_ref, o_ref, *, kh: int, kw: int,
             win = x_ref[0, dh:dh + hspan, dw:dw + wspan, :]  # windowing
             win = win[::stride, ::stride]                    # kept rows/cols
             acc = acc + jnp.dot(win.reshape(Hs * Ws, cin), w_ref[dh, dw],
+                                precision=_F32,
                                 preferred_element_type=jnp.float32)
     acc = acc + b_ref[...]                                    # bias add
     if activation == "sigmoid":                               # activation unit
@@ -59,7 +61,7 @@ def conv2d_pallas(x: jnp.ndarray, w: jnp.ndarray, b: jnp.ndarray, *,
                   stride: int = 1,
                   apply_sigmoid: bool = False,
                   activation: str | None = None,
-                  interpret: bool = True) -> jnp.ndarray:
+                  interpret: bool) -> jnp.ndarray:
     """x (B, H+kh-1, W+kw-1, Cin) pre-padded; w (kh, kw, Cin, Cout); b (Cout,).
     Returns (B, ceil(H/stride), ceil(W/stride), Cout) f32 — stride is realized
     NATIVELY: only the kept rows/columns are MAC'd and only the strided output

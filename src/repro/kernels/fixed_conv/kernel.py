@@ -10,7 +10,12 @@ Pipeline stages, fused per program instance (one image per grid step):
                     the DSP MAC array, one tap per unrolled step
   bias add       -> `fixed_add` (wraparound, or sign-checked saturation)
   PLAN sigmoid   -> shift-add piecewise-linear unit (optional epilogue)
-  maxpool 2x2/2  -> 3-comparator tree over strided views (optional epilogue)
+  maxpool 2x2/2  -> 3-comparator tree over row/col selects (optional
+                    epilogue; kernels/pooling.py)
+
+Taps and bias arrive in SMEM and are read as scalars, then broadcast to the
+tile before the limb multiply: the limb split bitcasts its operands, and
+Mosaic only bitcasts vectors.
 
 Why the limb decomposition: a Qm.n product needs the full 64-bit result of a
 32x32 multiply before the >> frac_bits renormalization, but the TPU (and
@@ -50,18 +55,12 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import fixed_point as fxp
+from repro.kernels.pooling import pool2x2
 
 _TAPS = ((0, 0), (0, 1), (1, 0), (1, 1))   # (dh, dw) per 2x2 kernel tap
-
-
-def _pool2x2(y: jnp.ndarray) -> jnp.ndarray:
-    """3-comparator tree on even-cropped (H, W); exact for int words."""
-    H, W = y.shape
-    y = y[:H - H % 2, :W - W % 2]
-    return jnp.maximum(jnp.maximum(y[::2, ::2], y[::2, 1::2]),
-                       jnp.maximum(y[1::2, ::2], y[1::2, 1::2]))
 
 
 def _fixed_conv_kernel(x_ref, w_ref, b_ref, o_ref, *,
@@ -73,19 +72,20 @@ def _fixed_conv_kernel(x_ref, w_ref, b_ref, o_ref, *,
     acc = jnp.zeros((H, W), jnp.int32)
     for t, (dh, dw) in enumerate(_TAPS):               # unrolled MAC taps
         win = x[dh:dh + H, dw:dw + W]                  # windowing module
-        acc = acc + fxp.fixed_mul(win, w_ref[t], cfg)  # limb MAC, int32 wrap
-    y = fxp.fixed_add(acc, b_ref[0], cfg)              # bias add
+        w = jnp.full((H, W), w_ref[t], jnp.int32)      # SMEM tap, broadcast
+        acc = acc + fxp.fixed_mul(win, w, cfg)         # limb MAC, int32 wrap
+    y = fxp.fixed_add(acc, jnp.full((H, W), b_ref[0], jnp.int32), cfg)
     if activation == "plan":
         y = fxp.fixed_sigmoid_plan(y, cfg)             # shift-add PLAN unit
     if pool:
-        y = _pool2x2(y)                                # comparator tree
+        y = pool2x2(y)                                 # comparator tree
     o_ref[...] = y[None]
 
 
 def fixed_conv2d_pallas(x: jnp.ndarray, w4: jnp.ndarray, b: jnp.ndarray, *,
                         cfg: fxp.FixedPointConfig = fxp.Q16_16,
                         activation: str | None = None, pool: bool = False,
-                        interpret: bool = True) -> jnp.ndarray:
+                        interpret: bool) -> jnp.ndarray:
     """x (B, H+1, W+1) int32 pre-padded (SAME: 0 after); w4 (4,) int32 taps;
     b (1,) int32 bias word.  Returns (B, H, W) int32, or the pooled
     (B, H//2, W//2) when `pool` fuses the comparator-tree stage."""
@@ -99,8 +99,8 @@ def fixed_conv2d_pallas(x: jnp.ndarray, w4: jnp.ndarray, b: jnp.ndarray, *,
         grid=(B,),
         in_specs=[
             pl.BlockSpec((1, Hp, Wp), lambda i: (i, 0, 0)),
-            pl.BlockSpec((4,), lambda i: (0,)),
-            pl.BlockSpec((1,), lambda i: (0,)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
         ],
         out_specs=pl.BlockSpec((1, Ho, Wo), lambda i: (i, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((B, Ho, Wo), jnp.int32),
@@ -109,11 +109,11 @@ def fixed_conv2d_pallas(x: jnp.ndarray, w4: jnp.ndarray, b: jnp.ndarray, *,
 
 
 def _fixed_pool_kernel(x_ref, o_ref):
-    o_ref[...] = _pool2x2(x_ref[0])[None]
+    o_ref[...] = pool2x2(x_ref[0])[None]
 
 
 def fixed_maxpool2x2_pallas(x: jnp.ndarray, *,
-                            interpret: bool = True) -> jnp.ndarray:
+                            interpret: bool) -> jnp.ndarray:
     """x (B, H, W) int32, H/W even (wrapper crops) -> (B, H/2, W/2)."""
     B, H, W = x.shape
     return pl.pallas_call(
@@ -133,7 +133,7 @@ def _fixed_plan_kernel(x_ref, o_ref, *, cfg: fxp.FixedPointConfig):
 def fixed_sigmoid_plan_pallas(x: jnp.ndarray, *,
                               cfg: fxp.FixedPointConfig = fxp.Q16_16,
                               block_rows: int = 256,
-                              interpret: bool = True) -> jnp.ndarray:
+                              interpret: bool) -> jnp.ndarray:
     """x (R, C) int32, R a multiple of block_rows (wrapper pads) -> int32
     PLAN sigmoid words, the VPU shift-add activation unit."""
     R, C = x.shape

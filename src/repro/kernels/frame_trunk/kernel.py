@@ -8,11 +8,14 @@ single-source + 5 mixed-source conv launches at level 1, plus pools).
 This kernel is the ZynqNet/Solovyev-style whole-frame tiled dataflow: the
 grid walks spatial frame tiles, each program instance
 
-  DMA            copies its input tile PLUS a `HALO`-wide apron of rows/
-                 cols from the (zero-padded) frame in HBM/ANY into a VMEM
-                 scratch block — overlapping reads are inexpressible as a
-                 blocked `BlockSpec`, so the halo load is an explicit
-                 `pltpu.make_async_copy` with element offsets
+  input          receives its tile PLUS a `HALO`-wide apron of rows/cols
+                 of the (zero-padded) frame as one VMEM block — overlapping
+                 reads are inexpressible as a blocked `BlockSpec`, so the
+                 wrapper lays the frame out as a (H/th, W/tw, th+HALO,
+                 tw+HALO) stack of halo windows and the grid pipeline DMAs
+                 one window per program (a manual DMA at element offsets
+                 would need (8, 128)-aligned window shapes); the four conv
+                 taps and the bias word of each stage ride in SMEM
   level 0        4 masked-tap conv+PLAN maps over the tile extent + 2
                  (interior / last-row / last-col / corner, the quad-role
                  cascade of streaming/fcn_sweep.py), pooled 2x2/2 into the
@@ -25,8 +28,9 @@ grid walks spatial frame tiles, each program instance
 
 entirely in int32 Qm.n words, reusing the SAME `core/fixed_point` helpers
 as `kernels/fixed_conv` (16-bit-limb MAC, wraparound adds,
-`shift_right_round`, PLAN shift-add) — so the megakernel cannot drift from
-the per-stage kernels it replaces.  Word-exactness vs the composed sweep is
+`shift_right_round`, PLAN shift-add) and the same `kernels/pooling`
+selects — so the megakernel cannot drift from the per-stage kernels it
+replaces.  Word-exactness vs the composed sweep is
 an associativity argument, not a tolerance: every masked partial conv wraps
 its accumulator into the Qm.n word exactly where `backends.conv_fixed`
 does, and wraparound addition is associative mod 2**total_bits (saturating
@@ -37,6 +41,11 @@ Why the halo is 3: level-0 convs at the tile's last row read 1 row down
 conv rows, i.e. input rows), and level-1 convs read 1 pooled row down —
 3 input rows/cols past the tile on the bottom/right, 0 on the top/left
 (the SAME convention is 0-before/1-after, so tiles never look up-left).
+
+Each program writes its quad tile as one whole (4, th/4, tw/4) block of a
+(H/th, W/tw, 4, th/4, tw/4) output, so the block always equals the array's
+last dims and meets Mosaic's (8, 128) block rule for any tile; the wrapper
+reassembles the (4, H/4, W/4) quad outside the kernel.
 
 Interpret mode is bit-identical to compiled mode for the same reason as
 kernels/fixed_conv: every op is integer with exactly one defined result.
@@ -51,6 +60,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import fixed_point as fxp
+from repro.kernels.pooling import pool_mix, pool_quadrants
 
 HALO = 3                      # input rows/cols of bottom/right apron per tile
 
@@ -76,37 +86,17 @@ def _conv(x, w_ref, taps, bias, cfg, Ho, Wo):
     for t in taps:
         dh, dw = _TAPS[t]
         win = x[dh:dh + Ho, dw:dw + Wo]
-        acc = acc + fxp.fixed_mul(win, w_ref[t], cfg)
-    return fxp.fixed_add(acc, bias, cfg)
+        w = jnp.full((Ho, Wo), w_ref[t], jnp.int32)   # SMEM tap, broadcast
+        acc = acc + fxp.fixed_mul(win, w, cfg)
+    return fxp.fixed_add(acc, jnp.full((Ho, Wo), bias, jnp.int32), cfg)
 
 
-def _pool_mix(e, o):
-    """2D sibling of fcn_sweep._pool_mix: even output rows pool conv rows
-    from `e`, odd rows from `o`."""
-    return jnp.maximum(jnp.maximum(e[::2, ::2], e[::2, 1::2]),
-                       jnp.maximum(o[1::2, ::2], o[1::2, 1::2]))
-
-
-def _pool_quadrants(tl, tr, bl, br):
-    """2D sibling of fcn_sweep._pool_quadrants: one source per window
-    quadrant."""
-    return jnp.maximum(jnp.maximum(tl[::2, ::2], tr[::2, 1::2]),
-                       jnp.maximum(bl[1::2, ::2], br[1::2, 1::2]))
-
-
-def _frame_trunk_kernel(x_hbm, w1_ref, b1_ref, w2_ref, b2_ref, o_ref,
-                        xt_ref, sem, *, cfg: fxp.FixedPointConfig,
+def _frame_trunk_kernel(x_ref, w1_ref, b1_ref, w2_ref, b2_ref, o_ref, *,
+                        cfg: fxp.FixedPointConfig,
                         th: int, tw: int, H: int, W: int):
     i = pl.program_id(0)
     j = pl.program_id(1)
-
-    # -- halo DMA: (th+HALO, tw+HALO) block of the zero-padded frame -------
-    dma = pltpu.make_async_copy(
-        x_hbm.at[pl.ds(i * th, th + HALO), pl.ds(j * tw, tw + HALO)],
-        xt_ref, sem)
-    dma.start()
-    dma.wait()
-    x = xt_ref[...]
+    x = x_ref[...]              # (th+HALO, tw+HALO) window of the padded frame
 
     def plan(y):
         return fxp.fixed_sigmoid_plan(y, cfg)
@@ -127,10 +117,10 @@ def _frame_trunk_kernel(x_hbm, w1_ref, b1_ref, w2_ref, b2_ref, o_ref,
     s_il = plan(_conv(x, w1_ref, _T_LEFT, b1, cfg, h0, w0))
     s_ll = plan(_conv(x, w1_ref, _T_00, b1, cfg, h0, w0))
 
-    I1 = _pool_mix(s_ii, s_ii)                       # interior
-    B1 = _pool_mix(s_ii, s_li)                       # last row
-    R1 = _pool_quadrants(s_ii, s_il, s_ii, s_il)     # last col
-    C1 = _pool_quadrants(s_ii, s_il, s_li, s_ll)     # corner
+    I1 = pool_mix(s_ii, s_ii)                        # interior
+    B1 = pool_mix(s_ii, s_li)                        # last row
+    R1 = pool_quadrants(s_ii, s_il, s_ii, s_il)      # last col
+    C1 = pool_quadrants(s_ii, s_il, s_li, s_ll)      # corner
 
     # -- frame-edge masking: a level-1 position at global row H/2 / col W/2
     # exists only as this tile's halo over the frame's zero padding; its
@@ -161,40 +151,43 @@ def _frame_trunk_kernel(x_hbm, w1_ref, b1_ref, w2_ref, b2_ref, o_ref,
     s_pl2 = plan(add(c(R1, w2_ref, _T_00, b2), c(C1, w2_ref, _T_10, zero)))
     s_lp2 = plan(add(c(B1, w2_ref, _T_00, b2), c(C1, w2_ref, _T_01, zero)))
 
-    o_ref[...] = jnp.stack([
-        _pool_mix(s_ii2, s_ii2),                         # interior
-        _pool_mix(s_pi2, s_li2),                         # last row
-        _pool_quadrants(s_ip2, s_il2, s_ip2, s_il2),     # last col
-        _pool_quadrants(s_pp2, s_pl2, s_lp2, s_ll2),     # corner
-    ])
+    o_ref[0] = pool_mix(s_ii2, s_ii2)                        # interior
+    o_ref[1] = pool_mix(s_pi2, s_li2)                        # last row
+    o_ref[2] = pool_quadrants(s_ip2, s_il2, s_ip2, s_il2)    # last col
+    o_ref[3] = pool_quadrants(s_pp2, s_pl2, s_lp2, s_ll2)    # corner
 
 
 def frame_trunk_pallas(xp: jnp.ndarray, w1: jnp.ndarray, b1: jnp.ndarray,
                        w2: jnp.ndarray, b2: jnp.ndarray, *,
                        cfg: fxp.FixedPointConfig = fxp.Q16_16,
                        th: int, tw: int,
-                       interpret: bool = True) -> jnp.ndarray:
+                       interpret: bool) -> jnp.ndarray:
     """xp (H+HALO, W+HALO) int32 frame pre-padded with HALO zero rows/cols
     bottom+right; w1/w2 (4,) int32 taps; b1/b2 (1,) int32 bias words;
     (th, tw) the tile extent (each divides H/W, multiples of 4).  Returns
     the (4, H/4, W/4) int32 level-2 role-map quad
     [interior, last_row, last_col, corner] in ONE launch."""
     H, W = xp.shape[0] - HALO, xp.shape[1] - HALO
+    nI, nJ = H // th, W // tw
     kern = functools.partial(_frame_trunk_kernel, cfg=cfg, th=th, tw=tw,
                              H=H, W=W)
-    return pl.pallas_call(
+    windows = jnp.stack([
+        jnp.stack([xp[i * th:i * th + th + HALO, j * tw:j * tw + tw + HALO]
+                   for j in range(nJ)])
+        for i in range(nI)])                   # (nI, nJ, th+HALO, tw+HALO)
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
+    tiles = pl.pallas_call(
         kern,
-        grid=(H // th, W // tw),
+        grid=(nI, nJ),
         in_specs=[
-            pl.BlockSpec(memory_space=pltpu.ANY),        # manual halo DMA
-            pl.BlockSpec((4,), lambda i, j: (0,)),
-            pl.BlockSpec((1,), lambda i, j: (0,)),
-            pl.BlockSpec((4,), lambda i, j: (0,)),
-            pl.BlockSpec((1,), lambda i, j: (0,)),
+            pl.BlockSpec((None, None, th + HALO, tw + HALO),
+                         lambda i, j: (i, j, 0, 0)),
+            smem, smem, smem, smem,
         ],
-        out_specs=pl.BlockSpec((4, th // 4, tw // 4), lambda i, j: (0, i, j)),
-        out_shape=jax.ShapeDtypeStruct((4, H // 4, W // 4), jnp.int32),
-        scratch_shapes=[pltpu.VMEM((th + HALO, tw + HALO), jnp.int32),
-                        pltpu.SemaphoreType.DMA],
+        out_specs=pl.BlockSpec((None, None, 4, th // 4, tw // 4),
+                               lambda i, j: (i, j, 0, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((nI, nJ, 4, th // 4, tw // 4),
+                                       jnp.int32),
         interpret=interpret,
-    )(xp, w1, b1, w2, b2)
+    )(windows, w1, b1, w2, b2)
+    return tiles.transpose(2, 0, 3, 1, 4).reshape(4, H // 4, W // 4)

@@ -19,7 +19,10 @@ all x4 bytes (`frame_trunk_vmem_bytes`).  The chooser scans tile extents
 that divide the frame and are multiples of 4 (two 2x2/2 pools), keeping
 the largest-area tile that fits the 14 MB budget — a 112x112 frame runs as
 one tile (~900 KB), 512x512 splits into two 512x256 tiles (~9 MB each), so
-the acceptance-bar 512 frame genuinely exercises tile seams.
+the acceptance-bar 512 frame genuinely exercises tile seams.  The model is
+conservative: the TPU compiler (AOT for a described v5e) accepts tiles the
+model rejects, 512x512 and 1024x512 among them, under its default scoped
+VMEM limit, because it does not keep every map resident at once.
 
 The perf ledger's bytes-moved account (`analysis/mfu.py`,
 `trunk_workload(..., "sweep_megakernel")`) counts this kernel's off-chip
@@ -121,7 +124,7 @@ def frame_trunk_quad(x: jnp.ndarray, w1: jnp.ndarray, b1: jnp.ndarray,
     bias words.  `tile=None` picks the tile via `choose_tile`; an explicit
     (th, tw) must divide the frame on the pooled lattice (tests use small
     forced tiles to exercise seams on small frames).  `interpret=None`
-    follows `core.runtime` (the process-wide real-device switch)."""
+    follows `core.runtime` (the process-wide interpret default)."""
     H, W = x.shape
     check_frame_geometry(H, W)
     if cfg.saturate:

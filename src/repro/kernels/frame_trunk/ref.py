@@ -11,7 +11,7 @@ battery pins each pair so a bug in the kernel's tiling/halo bookkeeping
 cannot hide behind a matching bug in the sweep (or vice versa).
 
 The oracle is deliberately UNTILED — one whole-frame computation — so it
-knows nothing about halos, DMA offsets, or edge masking: exactly the
+knows nothing about halos, window offsets, or edge masking: exactly the
 things the megakernel must get right to match it.
 """
 from __future__ import annotations

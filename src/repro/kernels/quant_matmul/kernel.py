@@ -42,7 +42,7 @@ def _qmm_kernel(x_ref, w_ref, sx_ref, sw_ref, o_ref, acc_ref):
 def quant_matmul_pallas(xq: jnp.ndarray, wq: jnp.ndarray,
                         sx: jnp.ndarray, sw: jnp.ndarray, *,
                         bm: int = 256, bn: int = 256, bk: int = 512,
-                        interpret: bool = True) -> jnp.ndarray:
+                        interpret: bool) -> jnp.ndarray:
     """xq (M,K) int8, wq (K,N) int8, sx (M,) f32 row scales, sw (N,) f32
     per-channel scales -> (M,N) f32.  M,K,N must be multiples of the block
     sizes (the ops.py wrapper pads)."""
@@ -84,7 +84,7 @@ def _fixed_mm_kernel(x_ref, w_ref, b_ref, o_ref, *, cfg: fxp.FixedPointConfig):
 
 def fixed_matmul_pallas(x: jnp.ndarray, w: jnp.ndarray, b: jnp.ndarray, *,
                         cfg: fxp.FixedPointConfig = fxp.Q16_16,
-                        bm: int = 128, interpret: bool = True) -> jnp.ndarray:
+                        bm: int = 128, interpret: bool) -> jnp.ndarray:
     """x (M,K) int32 Qm.n, w (K,N) int32, b (N,) int32 -> (M,N) int32.
     M must be a multiple of bm (the ops.py wrapper pads); K and N stay whole
     so the per-row MAC sweep lives in one program instance."""

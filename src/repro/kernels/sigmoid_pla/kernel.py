@@ -25,7 +25,7 @@ def _plan_kernel(x_ref, o_ref):
 
 
 def sigmoid_pla_pallas(x: jnp.ndarray, *, block_rows: int = 256,
-                       interpret: bool = True) -> jnp.ndarray:
+                       interpret: bool) -> jnp.ndarray:
     """x (R, C) f32, R a multiple of block_rows (wrapper pads)."""
     R, C = x.shape
     return pl.pallas_call(

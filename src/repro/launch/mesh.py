@@ -2,17 +2,16 @@
 never touches jax device state."""
 from __future__ import annotations
 
+from typing import Sequence
+
 import jax
 
 
 def _make_mesh(shape, axes, devices=None):
-    """jax.make_mesh across jax versions: `axis_types` landed after 0.4.x;
-    pass it where it exists (Auto on every axis, the behaviour the sharded
-    paths assume), plain call where it doesn't."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    kwargs = {} if axis_type is None else {
-        "axis_types": (axis_type.Auto,) * len(axes)}
-    return jax.make_mesh(shape, axes, devices=devices, **kwargs)
+    """jax.make_mesh with Auto on every axis, the behaviour the sharded
+    paths assume."""
+    return jax.make_mesh(shape, axes, devices=devices,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -30,11 +29,14 @@ def make_host_mesh(model_axis: int = 1):
     return _make_mesh((data, model_axis), ("data", "model"))
 
 
-def make_serving_mesh(n_devices: int | None = None):
+def make_serving_mesh(n_devices: int | None = None, *,
+                      devices: Sequence[jax.Device] | None = None):
     """Pure data-parallel serving mesh: all (or the first `n_devices`)
     local devices on one "data" axis — the vision engine's batch DP mesh.
-    Works degenerate on 1 CPU device and scales to a full host of chips."""
-    devs = jax.devices()
+    `devices` pins the mesh to an explicit device list instead (a replica
+    on its own chip is `make_serving_mesh(devices=[d])`).  Works degenerate
+    on 1 CPU device and scales to a full host of chips."""
+    devs = list(jax.devices() if devices is None else devices)
     if n_devices is not None:
         devs = devs[:n_devices]
     return _make_mesh((len(devs),), ("data",), devices=devs)

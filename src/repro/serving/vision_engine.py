@@ -34,10 +34,10 @@ bursts reports its real service rate instead of one deflated by idle gaps —
 Pass a `jax.sharding.Mesh` and the jitted step shards the batch dim across
 the mesh's data axes (the vision rules preset in `distributed/sharding.py`):
 inputs/outputs carry a `NamedSharding`, the padded batch size is rounded up
-to a multiple of the mesh batch axes, and on 1 device the whole thing
-degenerates to the unsharded program — same engine code on a laptop CPU and
-a pod slice.  For scaling across *separate* engines (distinct backends or
-mesh slices) see `serving/router.py`.
+to a multiple of the mesh batch axes, and each device runs the whole
+forward on its own batch shard under `shard_map` — same engine code on a
+laptop CPU and a pod slice.  For scaling across *separate* engines
+(distinct backends, devices or mesh slices) see `serving/router.py`.
 
 Sibling of `serving/engine.py` (the LM continuous-batching engine); this one
 is the image-classification half of the serving story.
@@ -56,7 +56,6 @@ Usage:
 from __future__ import annotations
 
 import collections
-import contextlib
 import dataclasses
 import threading
 import time
@@ -132,11 +131,10 @@ class VisionEngine:
     the jitted forward, and timestamps completions after
     `block_until_ready` so reported latency is honest wall clock.
 
-    With `mesh=` the step is traced under the vision sharding rules and the
-    batch axis is split across the mesh (batch_size is rounded UP to the
-    nearest multiple of the mesh batch axes so every device gets equal full
-    shards).  The ambient mesh context is part of jax's jit cache key on
-    the versions we support, so the engine re-enters it around every step.
+    With `mesh=` the batch axis is split across the mesh (batch_size is
+    rounded UP to the nearest multiple of the mesh batch axes so every
+    device gets equal full shards) and every device runs the forward on its
+    shard.
 
     Thread model: all bookkeeping lives under one condition variable; the
     jitted compute runs outside it, so submitters never block on the
@@ -167,13 +165,15 @@ class VisionEngine:
         if mesh is not None:
             mult = shd.vision_batch_multiple(mesh)
             self.batch_size = -(-self.batch_size // mult) * mult  # ceil to mult
-            self._rules = shd.make_vision_rules(mesh)
-            batch_spec = self._rules["batch"]
+            batch_spec = shd.make_vision_rules(mesh)["batch"]
             self._in_sharding = NamedSharding(
                 mesh, P(batch_spec, *(None,) * len(self.image_shape)))
             self._out_sharding = NamedSharding(mesh, P(batch_spec, None))
         # quantize once at engine build (the paper bakes weights at synthesis)
         self.params = self.backend.prepare_params(params)
+        if mesh is not None:          # resident on the mesh's own devices
+            self.params = jax.device_put(self.params,
+                                         NamedSharding(mesh, P()))
         self._step_fn = self._build_step()
         self._cond = threading.Condition()
         self._queue: collections.deque[VisionRequest] = collections.deque()
@@ -209,27 +209,28 @@ class VisionEngine:
         self._fault: BaseException | None = None
         if warmup:                    # compile outside the serving clock
             zeros = jnp.zeros((self.batch_size,) + self.image_shape, jnp.float32)
-            with self._mesh_ctx():
-                self._step_fn(self.params, zeros).block_until_ready()
-
-    def _mesh_ctx(self):
-        return self.mesh if self.mesh is not None else contextlib.nullcontext()
+            self._step_fn(self.params, zeros).block_until_ready()
 
     def _build_step(self):
         be = self.backend
-        if self.mesh is None:
-            return jax.jit(lambda p, x: smallnet.apply(p, x, backend=be))
-        rules = self._rules
 
         def fwd(p, x):
-            # the rules context is live during TRACE, which is when the
-            # logical->physical constraint specs are resolved
-            with shd.sharding_rules(rules):
-                return smallnet.apply(p, x, backend=be)
+            return smallnet.apply(p, x, backend=be)
 
+        if self.mesh is None:
+            return jax.jit(fwd)
+        # per-example compute is independent, so each device runs the whole
+        # forward on its batch shard with no collectives.  shard_map, not
+        # GSPMD propagation: Mosaic kernels cannot be partitioned
+        # automatically.  Pallas outputs carry no varying-axes annotation,
+        # hence check_vma=False.
+        step = jax.shard_map(fwd, mesh=self.mesh,
+                             in_specs=(P(), self._in_sharding.spec),
+                             out_specs=self._out_sharding.spec,
+                             check_vma=False)
         # params replicated (510 params ~ 2 KB; a pytree-prefix sharding
         # broadcasts to every leaf), batch split across the mesh data axes
-        return jax.jit(fwd,
+        return jax.jit(step,
                        in_shardings=(NamedSharding(self.mesh, P()),
                                      self._in_sharding),
                        out_shardings=self._out_sharding)
@@ -363,9 +364,11 @@ class VisionEngine:
             batch = np.zeros((self.batch_size,) + self.image_shape, np.float32)
             for i, r in enumerate(reqs):
                 batch[i] = r.image
-            with self._mesh_ctx(), T.device_step_annotation(
+            with T.device_step_annotation(
                     f"vision_step/{self.backend.name}"):
-                scores = self._step_fn(self.params, jnp.asarray(batch))
+                x = (jnp.asarray(batch) if self.mesh is None
+                     else jax.device_put(batch, self._in_sharding))
+                scores = self._step_fn(self.params, x)
                 scores.block_until_ready()
         except Exception:
             # a faulted step sheds its batch (reason "fault") rather than

@@ -3,11 +3,13 @@
     PYTHONPATH=src python tests/golden/gen_frame_trunk_golden.py
 
 Freezes the megakernel trunk's level-2 role-map quad (interior / last_row /
-last_col / corner, 28x28 int32 words each) over the deterministic 112x112
-synthetic frame (SyntheticVideoSource seed 7, frame 0) with the seeded
-benchmark params, in BOTH deployed formats: Q16.16 and Q8.8.  Generation
-cross-checks four independent routes per format and fails loudly on any
-disagreement:
+last_col / corner, 28x28 int32 words each) over one deterministic 112x112
+frame with smallNet's seeded params, in BOTH deployed formats: Q16.16 and
+Q8.8.  Like sweep_golden.json, the file stores its inputs (the Q16.16
+parameter and frame words; the Q8.8 run quantizes the same float values)
+and the generator and tests read them from there — see gen_sweep_golden.py
+for how they were drawn.  Generation cross-checks four independent routes
+per format and fails loudly on any disagreement:
 
   * the one-launch megakernel on the emulated "fixed" backend vs on
     "fixed_pallas" (same kernel, both substrate plumbings);
@@ -16,7 +18,7 @@ disagreement:
     already pins);
   * the megakernel vs the untiled numpy int64 oracle
     (kernels/frame_trunk/ref.py), which knows nothing about tiles, halos,
-    or DMA offsets.
+    or window offsets.
 
 So the frozen vectors pin the megakernel's tiling/halo bookkeeping against
 vectors that cannot silently regenerate themselves — the CI golden job
@@ -28,16 +30,16 @@ import json
 import pathlib
 
 import numpy as np
+from gen_sweep_golden import decode_inputs, golden_inputs
 
 from repro.core import backends as B
 from repro.core import fixed_point as fxp
-from repro.core import smallnet
 from repro.kernels.frame_trunk.ref import frame_trunk_quad_ref
 from repro.streaming.fcn_sweep import sweep_feature_maps
-from repro.streaming.sources import SyntheticVideoSource
 
 MAPS = ("interior", "last_row", "last_col", "corner")
 FORMATS = {"q16_16": fxp.Q16_16, "q8_8": fxp.Q8_8}
+PATH = pathlib.Path(__file__).parent / "frame_trunk_golden.json"
 
 
 def _check_equal(name, a, b):
@@ -47,26 +49,27 @@ def _check_equal(name, a, b):
 
 
 def main() -> None:
-    params = smallnet.seeded_params()
-    frame = SyntheticVideoSource(n_frames=1, seed=7).frames()[0]
+    inputs = golden_inputs(PATH)
+    params, pixels = decode_inputs(inputs)
 
     out = {
         "frame": {"source": "SyntheticVideoSource(n_frames=1, seed=7)",
-                  "index": 0, "shape": [112, 112]},
+                  "index": 0, "shape": list(pixels.shape[:2])},
+        "inputs": inputs,
         "maps": {},
     }
     for fmt, cfg in FORMATS.items():
         be = B.FixedBackend(name=f"fixed_{fmt}", cfg=cfg)
         bp = B.FixedPallasBackend(name=f"fixed_pallas_{fmt}", cfg=cfg)
-        mega = sweep_feature_maps(params, frame.pixels, backend=be,
+        mega = sweep_feature_maps(params, pixels, backend=be,
                                   megakernel=True)
-        mega_p = sweep_feature_maps(params, frame.pixels, backend=bp,
+        mega_p = sweep_feature_maps(params, pixels, backend=bp,
                                     megakernel=True)
-        comp = sweep_feature_maps(params, frame.pixels, backend=be,
+        comp = sweep_feature_maps(params, pixels, backend=be,
                                   megakernel=False)
 
         p = be.prepare_params(params)
-        x = np.asarray(be.ingest(np.asarray(frame.pixels, np.float32)[None]))
+        x = np.asarray(be.ingest(pixels[None]))
         oracle = frame_trunk_quad_ref(x[0], np.asarray(p["conv1"]["w"]),
                                       np.asarray(p["conv1"]["b"]),
                                       np.asarray(p["conv2"]["w"]),
@@ -82,9 +85,8 @@ def main() -> None:
                          words, oracle[k])
             out["maps"][fmt][name] = words.tolist()
 
-    path = pathlib.Path(__file__).parent / "frame_trunk_golden.json"
-    path.write_text(json.dumps(out, indent=1) + "\n")
-    print(f"wrote {path} ({path.stat().st_size} bytes)")
+    PATH.write_text(json.dumps(out, indent=1) + "\n")
+    print(f"wrote {PATH} ({PATH.stat().st_size} bytes)")
 
 
 if __name__ == "__main__":

@@ -2,8 +2,9 @@
 
 tests/golden/frame_trunk_golden.json freezes the megakernel's level-2 quad
 words over the deterministic 112x112 synthetic frame in BOTH deployed
-formats (Q16.16 and Q8.8).  Both fixed substrates must reproduce every word
-through the one-launch route — any drift in the tile chooser, the halo DMA,
+formats (Q16.16 and Q8.8), from inputs (Q16.16 parameter and frame words)
+stored in the same file.  Both fixed substrates must reproduce every word
+through the one-launch route — any drift in the tile chooser, the halo windows,
 the in-kernel edge masking, or the underlying arithmetic fails here first,
 against vectors that cannot silently regenerate themselves (the CI golden
 job diffs a fresh generation).
@@ -21,7 +22,6 @@ from repro.core import backends as B
 from repro.core import fixed_point as fxp
 from repro.core import smallnet
 from repro.streaming.fcn_sweep import sweep_feature_maps
-from repro.streaming.sources import SyntheticVideoSource
 
 _GOLDEN = json.loads(
     (pathlib.Path(__file__).parent / "golden"
@@ -33,14 +33,14 @@ _MAPS = ("interior", "last_row", "last_col", "corner")
 
 @pytest.fixture(scope="module")
 def params():
-    return smallnet.seeded_params()
+    return smallnet.params_from_words(_GOLDEN["inputs"]["params"])
 
 
 @pytest.fixture(scope="module")
 def frame():
-    f = SyntheticVideoSource(n_frames=1, seed=7).frames()[0]
-    assert list(f.pixels.shape[:2]) == _GOLDEN["frame"]["shape"]
-    return f
+    words = np.asarray(_GOLDEN["inputs"]["frame"], np.int32)
+    assert list(words.shape) == _GOLDEN["frame"]["shape"]
+    return np.asarray(fxp.from_fixed(words))[..., None]
 
 
 def test_golden_covers_both_formats_and_all_maps():
@@ -56,7 +56,7 @@ def test_golden_covers_both_formats_and_all_maps():
 def test_megakernel_maps_golden(params, frame, fmt, kind):
     cls = B.FixedBackend if kind == "fixed" else B.FixedPallasBackend
     be = cls(name=f"{kind}_{fmt}_golden", cfg=_FORMATS[fmt])
-    maps = sweep_feature_maps(params, frame.pixels, backend=be,
+    maps = sweep_feature_maps(params, frame, backend=be,
                               megakernel=True)
     for name in _MAPS:
         np.testing.assert_array_equal(

@@ -182,3 +182,29 @@ def test_fixed_conv_frame_extent_and_budget(rng):
     # a frame past ~670x670 exceeds input + limb-temporary VMEM
     with pytest.raises(ValueError, match="VMEM"):
         fixed_conv2d(jnp.zeros((1, 700, 700), jnp.int32), w4, b, cfg=cfg)
+
+
+@pytest.mark.parametrize("H,W", [(28, 28), (14, 14), (114, 58), (7, 9)])
+@pytest.mark.parametrize("dtype", [jnp.int32, jnp.float32])
+def test_pooling_selects_match_strided_slices(H, W, dtype, rng):
+    """The Mosaic-lowerable pools (kernels/pooling.py) are exactly the
+    strided-slice comparator trees they replace."""
+    from repro.kernels.pooling import pool2x2, pool_mix, pool_quadrants
+    maps = [np.asarray(rng.integers(-2**31, 2**31 - 1, (H, W)), np.int64)
+            .astype(np.dtype(dtype)) for _ in range(4)]
+    y = maps[0][:H - H % 2, :W - W % 2]
+    np.testing.assert_array_equal(
+        np.asarray(pool2x2(jnp.asarray(maps[0]))),
+        np.maximum(np.maximum(y[::2, ::2], y[::2, 1::2]),
+                   np.maximum(y[1::2, ::2], y[1::2, 1::2])))
+    if H % 2 or W % 2:
+        return
+    tl, tr, bl, br = maps
+    want = np.maximum(np.maximum(tl[::2, ::2], tr[::2, 1::2]),
+                      np.maximum(bl[1::2, ::2], br[1::2, 1::2]))
+    got = pool_quadrants(*(jnp.asarray(m) for m in maps))
+    np.testing.assert_array_equal(np.asarray(got), want)
+    np.testing.assert_array_equal(
+        np.asarray(pool_mix(jnp.asarray(tl), jnp.asarray(bl))),
+        np.maximum(np.maximum(tl[::2, ::2], tl[::2, 1::2]),
+                   np.maximum(bl[1::2, ::2], bl[1::2, 1::2])))

@@ -1,11 +1,15 @@
-"""The process-wide interpret switch (core/runtime.py).
+"""The process-wide interpret switch and compile cache (core/runtime.py).
 
 Pure plumbing tests — no compiled-mode execution (CPU CI has no device to
-compile Pallas for): the default resolves, explicit flags win, flipping the
-switch fires the registered cache-reset hooks exactly once per real change,
-and the registered backends defer to the process default (interpret=None)
-rather than pinning their own.
+compile Pallas for): the default follows the platform, explicit flags win,
+flipping the switch fires the registered cache-reset hooks exactly once per
+real change, the registered backends defer to the process default
+(interpret=None) rather than pinning their own, and the compile cache lands
+where `JAX_COMPILATION_CACHE_DIR` says or in the checkout's `.jax_cache/`.
 """
+import pathlib
+
+import jax
 import pytest
 
 from repro.core import backends as B
@@ -55,3 +59,26 @@ def test_registered_backends_follow_process_default():
             f"None so backends.set_interpret governs it")
     assert B.set_interpret is runtime.set_interpret
     assert B.interpret_default is runtime.interpret_default
+
+
+def test_default_follows_platform(monkeypatch):
+    """Interpret exactly on a CPU backend; compiled kernels elsewhere."""
+    monkeypatch.setattr(runtime, "_INTERPRET", None)
+    assert runtime.interpret_default() == (jax.default_backend() == "cpu")
+
+
+def test_compile_cache_dir(monkeypatch, tmp_path):
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        assert runtime.init_compile_cache() == str(tmp_path)
+        # JAX reads the variable itself; the helper sets no other directory
+        assert jax.config.jax_compilation_cache_dir == before
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        root = pathlib.Path(__file__).resolve().parents[1]
+        path = runtime.init_compile_cache()
+        assert path == str(root / ".jax_cache")
+        assert jax.config.jax_compilation_cache_dir == path
+        assert ".jax_cache/" in (root / ".gitignore").read_text().split()
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)

@@ -215,6 +215,24 @@ def test_vision_engine_sharded_serves_identical_words(vision_setup):
     assert [r.pred for r in res_m] == [r.pred for r in res_u]
 
 
+def test_vision_engine_pinned_to_one_device(vision_setup):
+    """`make_serving_mesh(devices=[d])` pins an engine to device d — how a
+    router places one replica per chip — and the Pallas substrate runs
+    under the mesh step's shard_map word-for-word."""
+    params, images = vision_setup
+    d = jax.devices()[-1]
+    eng = VisionEngine(params, backend="fixed_pallas", batch_size=8,
+                       mesh=make_serving_mesh(devices=[d]))
+    assert all(leaf.devices() == {d}
+               for leaf in jax.tree_util.tree_leaves(eng.params))
+    res = eng.serve(list(images[:12]))
+    base = VisionEngine(params, backend="fixed_pallas",
+                        batch_size=8).serve(list(images[:12]))
+    np.testing.assert_array_equal(np.stack([r.scores for r in res]),
+                                  np.stack([r.scores for r in base]))
+    assert eng.stats()["accounted"] and eng.stats()["shed"] == 0
+
+
 def test_vision_engine_sharded_multi_device_subprocess(vision_setup):
     """8 virtual CPU devices: the engine rounds its batch to the mesh
     multiple, serves a ragged workload, and matches the unsharded engine

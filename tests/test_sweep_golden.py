@@ -3,7 +3,10 @@
 tests/golden/sweep_golden.json freezes the Q16.16 words of the full-frame
 sweep over a deterministic 112x112 synthetic frame: all four pooled role
 maps (interior / last_row / last_col / corner) and the stride-8 window
-scores.  Both fixed substrates must reproduce every word — any drift in the
+scores.  The file also stores the inputs (Q16.16 parameter and frame
+words), which the tests read from there — so the vectors pin the
+arithmetic, not the `jax.random` stream behind `smallnet.seeded_params`.
+Both fixed substrates must reproduce every word — any drift in the
 masked-weight edge maps, the decomposed accumulation, or the underlying
 conv/PLAN/pool arithmetic fails here first, against vectors that cannot
 silently regenerate themselves (the CI golden job diffs a fresh
@@ -18,9 +21,9 @@ import pathlib
 import numpy as np
 import pytest
 
+from repro.core import fixed_point as fxp
 from repro.core import smallnet
 from repro.streaming.fcn_sweep import FcnSweep, sweep_feature_maps
-from repro.streaming.sources import SyntheticVideoSource
 
 _GOLDEN = json.loads(
     (pathlib.Path(__file__).parent / "golden" / "sweep_golden.json").read_text())
@@ -28,14 +31,14 @@ _GOLDEN = json.loads(
 
 @pytest.fixture(scope="module")
 def params():
-    return smallnet.seeded_params()
+    return smallnet.params_from_words(_GOLDEN["inputs"]["params"])
 
 
 @pytest.fixture(scope="module")
 def frame():
-    f = SyntheticVideoSource(n_frames=1, seed=7).frames()[0]
-    assert list(f.pixels.shape[:2]) == _GOLDEN["frame"]["shape"]
-    return f
+    words = np.asarray(_GOLDEN["inputs"]["frame"], np.int32)
+    assert list(words.shape) == _GOLDEN["frame"]["shape"]
+    return np.asarray(fxp.from_fixed(words))[..., None]
 
 
 def _assert_words(got, want, what):
@@ -53,7 +56,7 @@ def test_golden_covers_all_role_maps():
 
 @pytest.mark.parametrize("backend", ("fixed", "fixed_pallas"))
 def test_role_maps_golden(params, frame, backend):
-    maps = sweep_feature_maps(params, frame.pixels, backend=backend)
+    maps = sweep_feature_maps(params, frame, backend=backend)
     for name, want in _GOLDEN["maps"].items():
         _assert_words(maps[name], want, f"{backend}/{name}")
 
