@@ -22,6 +22,11 @@ the flag is resolved in each wrapper's THIN UN-JITTED entry point, before
 compiled executable.  `set_interpret` still clears jit caches (and any
 registered model-level caches, e.g. the FCN sweep's per-geometry program
 cache) so previously compiled programs from the old mode are dropped.
+
+Every XLA backend compile in the process is counted in the metrics
+registry (`jax_compiles`, `jax_compile_seconds`) by one `jax.monitoring`
+listener, registered when this module is first imported: a compile inside
+a serving window shows as a step of the counter.
 """
 from __future__ import annotations
 
@@ -30,6 +35,8 @@ import pathlib
 from typing import Callable
 
 import jax
+
+from repro.obs import metrics as M
 
 # None = follow the platform (interpret exactly when the backend is the CPU)
 _INTERPRET: bool | None = None
@@ -88,3 +95,15 @@ def init_compile_cache() -> str:
         path = str(_CHECKOUT / ".jax_cache")
         jax.config.update("jax_compilation_cache_dir", path)
     return path
+
+
+_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+
+
+def _count_compile(event: str, duration_secs: float, **_) -> None:
+    if event == _COMPILE_EVENT:
+        M.REGISTRY.counter("jax_compiles").inc()
+        M.REGISTRY.counter("jax_compile_seconds").inc(duration_secs)
+
+
+jax.monitoring.register_event_duration_secs_listener(_count_compile)

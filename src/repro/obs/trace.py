@@ -11,8 +11,8 @@ CI trace smoke reconciles against the serving ledgers:
   "served", "dropped:<stage>/<reason>", or "shed:<reason>" — matching the
   component's own accounting (pipeline `frames_in == served + dropped`,
   engine `submitted == served + shed + pending`).  Interior spans
-  ("tile", "infer", "queue_wait", "device_step", ...) end "ok" unless the
-  work they cover failed.
+  ("infer", "queue_wait", "pipeline.tile", "engine.device_step", ...) end
+  "ok" unless the work they cover failed.
 
 Tracing is OFF by default and costs one `trace.get()` (a module attribute
 read) + None check per instrumentation site until `trace.enable()` turns
@@ -21,9 +21,14 @@ in a bounded `recorder.FlightRecorder` ring.  The `--trace` flag on
 `stream_table` / `goodput_table` / `stream_demo` is a thin wrapper around
 `enable()` + a JSONL dump of the ring.
 
-The opt-in jax.profiler bridge (`profile_device_steps()`) annotates every
-engine device step with a `jax.profiler.TraceAnnotation`, so a real-device
-profile (XProf/TensorBoard) shows the same step boundaries the spans do.
+`region(name)` is the span for synchronous work on one thread.  It always
+enters a `jax.profiler.TraceAnnotation(name)` — a no-op without a profiler
+session, a host event on the device trace's clock inside one — and, with
+tracing on, also records the `Span` in the ring.  Region names are
+`<layer>.<part>` (`pipeline.select`, `sweep.fetch`, `engine.post`, ...).
+Waits that cross coroutines or threads ("frame", "infer", "request",
+"queue_wait") stay ring spans: a profiler annotation must open and close
+on one thread with nothing else interleaved.
 """
 from __future__ import annotations
 
@@ -32,6 +37,8 @@ import dataclasses
 import itertools
 import time
 from typing import TYPE_CHECKING
+
+from jax.profiler import TraceAnnotation
 
 if TYPE_CHECKING:                                      # pragma: no cover
     from repro.obs.recorder import FlightRecorder
@@ -195,28 +202,49 @@ def get() -> Tracer | None:
     return _TRACER
 
 
-# -- jax.profiler bridge ------------------------------------------------------
+# -- regions: profiler annotation + ring span --------------------------------
 
-_PROFILE_STEPS = False
+class region:
+    """Context manager for one synchronous region of work on this thread:
 
+        with trace.region("sweep.fetch", parent=score_span) as span:
+            if span is not None:
+                span.tags["n"] = n
+            ...
 
-def profile_device_steps(on: bool = True) -> None:
-    """Opt in to wrapping every engine device step in a
-    `jax.profiler.TraceAnnotation` so spans and XProf timelines line up.
-    Off by default: annotations cost a TraceMe even without a live
-    profiler session."""
-    global _PROFILE_STEPS
-    _PROFILE_STEPS = bool(on)
+    Always a `jax.profiler.TraceAnnotation(name)`; without a profiler
+    session entering and leaving a region costs 0.83 us on a TPU v5e
+    host and 0.33-0.39 us on an x86 CPU host (0.36 and 0.16-0.17 us for a
+    `contextlib.nullcontext`).  With tracing on it also records a ring
+    span named `name` — trace id `trace_id`, else the parent's, else
+    `name` — that ends "error" when the body raises.  The span (None
+    when tracing is off) is what `with` binds: callers set its tags
+    inside, so nothing is built for them when tracing is off.  A class,
+    not a generator context manager, which costs 1.77 and 0.82 us on
+    those hosts."""
 
+    __slots__ = ("_ann", "_tracer", "_name", "_trace_id", "_parent", "span")
 
-def device_step_annotation(name: str):
-    """Context manager for the engine's jitted step: a profiler
-    annotation when `profile_device_steps()` is on, a nullcontext
-    otherwise (and a nullcontext if this jax build lacks the API)."""
-    if _PROFILE_STEPS:
-        try:
-            from jax.profiler import TraceAnnotation
-            return TraceAnnotation(name)
-        except ImportError:                            # pragma: no cover
-            pass
-    return contextlib.nullcontext()
+    def __init__(self, name: str, trace_id: str | None = None, *,
+                 parent: Span | None = None):
+        self._name = name
+        self._trace_id = trace_id
+        self._parent = parent
+        self.span = None
+
+    def __enter__(self) -> Span | None:
+        self._ann = TraceAnnotation(self._name)
+        self._ann.__enter__()
+        tr = self._tracer = _TRACER
+        if tr is not None:
+            p = self._parent
+            tid = self._trace_id or (p.trace_id if p is not None
+                                     else self._name)
+            self.span = tr.start(self._name, tid, parent=p)
+        return self.span
+
+    def __exit__(self, exc_type, exc, tb) -> None:
+        if self.span is not None:
+            self._tracer.end(self.span,
+                             "ok" if exc_type is None else "error")
+        self._ann.__exit__(exc_type, exc, tb)

@@ -474,7 +474,9 @@ class StageEngine:
             self._in_flight = 1
         t0 = time.perf_counter()
         try:
-            with T.device_step_annotation(f"stage_step/{self.name}"):
+            with T.region("engine.stage_step") as sp:
+                if sp is not None:
+                    sp.tags["stage"] = self.name
                 value = self._compute(req.payload)
         except Exception as e:
             with self._cond:

@@ -196,7 +196,6 @@ class VisionEngine:
         self._m_padded = reg.counter("engine_padded_slots", **labels)
         self._m_busy = reg.counter("engine_busy_seconds", **labels)
         self._m_queue = reg.gauge("engine_queue_depth", **labels)
-        self._m_occupancy = reg.gauge("engine_batch_occupancy", **labels)
         self._lat_hist = reg.histogram("engine_latency_seconds", **labels)
         self._next_uid = 0
         self._in_flight = 0
@@ -339,44 +338,49 @@ class VisionEngine:
     def step(self) -> int:
         """Serve one continuous batch: coalesce whatever is queued (up to
         batch_size), pad, run the jitted step, record results. Returns
-        #requests served (sheds don't count)."""
+        #requests served (sheds don't count).  Profiler regions, in order:
+        `engine.batch_form`, `engine.fill` (the padded batch built and
+        uploaded), `engine.device_step` (until `block_until_ready`, the
+        service floor included) and `engine.post` (predict, copy back,
+        results posted, waiters woken)."""
         tr = T.get()
         batch_idx = self._m_batches.value
-        bf = (tr.start("batch_form", f"step-{self._id}-{batch_idx}",
-                       batch_index=batch_idx, engine=self._id)
-              if tr is not None else None)
-        with self._cond:
-            reqs = self._form_batch_locked()
-            if not reqs:
-                if bf is not None:
-                    tr.end(bf, n_formed=0)
-                return 0
-            self._in_flight = len(reqs)
-        if bf is not None:
-            tr.end(bf, n_formed=len(reqs))
+        tid = f"step-{self._id}-{batch_idx}" if tr is not None else None
+        with T.region("engine.batch_form", tid) as bf:
+            with self._cond:
+                reqs = self._form_batch_locked()
+                if reqs:
+                    self._in_flight = len(reqs)
+            if bf is not None:
+                bf.tags.update(batch_index=batch_idx, engine=self._id,
+                               n_formed=len(reqs))
+        if not reqs:
+            return 0
         t0 = time.perf_counter()
-        ds = (tr.start("device_step", f"step-{self._id}-{batch_idx}",
-                       batch_index=batch_idx, engine=self._id,
-                       n_real=len(reqs),
-                       padded=self.batch_size - len(reqs))
-              if tr is not None else None)
         try:
-            batch = np.zeros((self.batch_size,) + self.image_shape, np.float32)
-            for i, r in enumerate(reqs):
-                batch[i] = r.image
-            with T.device_step_annotation(
-                    f"vision_step/{self.backend.name}"):
+            with T.region("engine.fill", tid):
+                batch = np.zeros((self.batch_size,) + self.image_shape,
+                                 np.float32)
+                for i, r in enumerate(reqs):
+                    batch[i] = r.image
                 x = (jnp.asarray(batch) if self.mesh is None
                      else jax.device_put(batch, self._in_sharding))
+            with T.region("engine.device_step", tid) as ds:
+                if ds is not None:
+                    ds.tags.update(batch_index=batch_idx, engine=self._id,
+                                   n_real=len(reqs),
+                                   padded=self.batch_size - len(reqs))
                 scores = self._step_fn(self.params, x)
                 scores.block_until_ready()
+                t_done = time.perf_counter()
+                if self.min_step_s > 0.0 and t_done - t0 < self.min_step_s:
+                    time.sleep(self.min_step_s - (t_done - t0))
+                    t_done = time.perf_counter()  # the floor IS the service time
         except Exception:
             # a faulted step sheds its batch (reason "fault") rather than
             # losing it: submitted == served + shed + pending must survive
             # replica death (the router treats "fault" sheds as unserved
             # and fails them over)
-            if ds is not None:
-                tr.end(ds, "error")
             with self._cond:
                 self._in_flight = 0
                 now = time.perf_counter()
@@ -384,48 +388,40 @@ class VisionEngine:
                     self._shed_locked(r.uid, "fault", r.t_submit, now,
                                       parent_span=r.parent_span, queued=True)
             raise
-        t_done = time.perf_counter()
-        if self.min_step_s > 0.0 and t_done - t0 < self.min_step_s:
-            time.sleep(self.min_step_s - (t_done - t0))
-            t_done = time.perf_counter()     # the floor IS the service time
-        if ds is not None:
-            tr.end(ds)
-        preds = np.asarray(smallnet.predict(scores))
-        scores_np = np.asarray(scores)
-        with self._cond:
-            self._m_busy.inc(t_done - t0)
-            self._t_last_done = t_done
-            for i, r in enumerate(reqs):
-                res = VisionResult(
-                    uid=r.uid, pred=int(preds[i]), scores=scores_np[i],
-                    t_submit=r.t_submit, t_done=t_done,
-                    batch_index=batch_idx, deadline=r.deadline)
-                self._results[r.uid] = res
-                self._lat_hist.observe(res.latency_s)
-                if r.deadline is not None and t_done <= r.deadline:
-                    self._deadline_ok += 1
-            self._m_served.inc(len(reqs))
-            self._m_batches.inc()
-            self._m_padded.inc(self.batch_size - len(reqs))
-            slots = self._m_batches.value * self.batch_size
-            self._m_occupancy.set((slots - self._m_padded.value) / slots)
-            self._in_flight = 0
-            self._cond.notify_all()
-        if tr is not None:
-            # materialize the batch's request/queue_wait spans AFTER the
-            # waiters are released, from timestamps the engine recorded
-            # anyway (t_submit, batch formation, t_done): the traced submit
-            # path allocates nothing, and t_done precedes the frame root's
-            # end so parent-window nesting still holds
-            t_formed = bf.t_end if bf is not None else t0
-            for r in reqs:
-                tid = (r.parent_span.trace_id if r.parent_span is not None
-                       else f"req-{self._id}-{r.uid}")
-                span = tr.emit("request", tid, r.t_submit, t_done, "served",
-                               parent=r.parent_span, uid=r.uid,
-                               batch_index=batch_idx)
-                tr.emit("queue_wait", tid, r.t_submit, t_formed,
-                        parent=span)
+        with T.region("engine.post", tid):
+            preds = np.asarray(smallnet.predict(scores))
+            scores_np = np.asarray(scores)
+            with self._cond:
+                self._m_busy.inc(t_done - t0)
+                self._t_last_done = t_done
+                for i, r in enumerate(reqs):
+                    res = VisionResult(
+                        uid=r.uid, pred=int(preds[i]), scores=scores_np[i],
+                        t_submit=r.t_submit, t_done=t_done,
+                        batch_index=batch_idx, deadline=r.deadline)
+                    self._results[r.uid] = res
+                    self._lat_hist.observe(res.latency_s)
+                    if r.deadline is not None and t_done <= r.deadline:
+                        self._deadline_ok += 1
+                self._m_served.inc(len(reqs))
+                self._m_batches.inc()
+                self._m_padded.inc(self.batch_size - len(reqs))
+                self._in_flight = 0
+                self._cond.notify_all()
+            if tr is not None:
+                # materialize the batch's request/queue_wait spans AFTER the
+                # waiters are released, from timestamps the engine recorded
+                # anyway (t_submit, batch formation, t_done): the traced
+                # submit path allocates nothing, and t_done precedes the
+                # frame root's end so parent-window nesting still holds
+                for r in reqs:
+                    rid = (r.parent_span.trace_id
+                           if r.parent_span is not None
+                           else f"req-{self._id}-{r.uid}")
+                    span = tr.emit("request", rid, r.t_submit, t_done,
+                                   "served", parent=r.parent_span, uid=r.uid,
+                                   batch_index=batch_idx)
+                    tr.emit("queue_wait", rid, r.t_submit, t0, parent=span)
         return len(reqs)
 
     def run(self) -> int:
@@ -459,8 +455,11 @@ class VisionEngine:
     def _serve_loop(self) -> None:
         while True:
             with self._cond:
-                while not self._queue and not self._stop_flag:
-                    self._cond.wait(timeout=0.05)
+                if not self._queue and not self._stop_flag:
+                    # the serving thread idle for lack of requests
+                    with T.region("engine.wait"):
+                        while not self._queue and not self._stop_flag:
+                            self._cond.wait(timeout=0.05)
                 if self._stop_flag and not self._queue:
                     return
             try:

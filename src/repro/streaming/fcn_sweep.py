@@ -79,6 +79,7 @@ import numpy as np
 from repro.core import backends as B
 from repro.core import runtime
 from repro.core import smallnet
+from repro.obs import trace as T
 from repro.streaming.sources import Frame
 from repro.streaming.tiler import Tiler, tile_positions
 
@@ -385,20 +386,31 @@ class FcnSweep(Tiler):
               backend: str | B.Backend = "ref") -> np.ndarray:
         """One jitted full-frame trunk pass + windowed dense head:
         (1, H, W, 1) frame -> (n_windows, 10) backend-native scores, in
-        `positions` order."""
-        be = B.get_backend(backend)
-        _check_saturation(be)
-        frames = np.asarray(frames, np.float32)
-        if frames.ndim == 3:
-            frames = frames[None]
-        if frames.shape[0] != 1:
-            raise ValueError(
-                f"FcnSweep.score takes one frame per call (the sweep is a "
-                f"per-frame device program), got batch {frames.shape[0]}")
-        H, W = frames.shape[1], frames.shape[2]
-        pos = tuple(self.positions((H, W)))
-        fn = _sweep_fn(be, (H, W), self.patch, pos, self.megakernel)
-        return np.asarray(fn(params, jnp.asarray(frames)))
+        `positions` order.  Profiler regions: `sweep.score`, holding
+        `sweep.prepare` (frame array, positions, program lookup),
+        `sweep.upload`, `sweep.dispatch` (the call until it returns) and
+        `sweep.fetch` (the wait for the scores and their copy back)."""
+        with T.region("sweep.score") as top:
+            with T.region("sweep.prepare", parent=top):
+                be = B.get_backend(backend)
+                _check_saturation(be)
+                frames = np.asarray(frames, np.float32)
+                if frames.ndim == 3:
+                    frames = frames[None]
+                if frames.shape[0] != 1:
+                    raise ValueError(
+                        f"FcnSweep.score takes one frame per call (the sweep "
+                        f"is a per-frame device program), got batch "
+                        f"{frames.shape[0]}")
+                H, W = frames.shape[1], frames.shape[2]
+                pos = tuple(self.positions((H, W)))
+                fn = _sweep_fn(be, (H, W), self.patch, pos, self.megakernel)
+            with T.region("sweep.upload", parent=top):
+                x = jnp.asarray(frames)
+            with T.region("sweep.dispatch", parent=top):
+                out = fn(params, x)
+            with T.region("sweep.fetch", parent=top):
+                return np.asarray(out)
 
     def _masses(self, tiles: np.ndarray,
                 positions: Sequence[tuple[int, int]]) -> np.ndarray:

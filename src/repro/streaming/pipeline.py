@@ -31,12 +31,16 @@ boundary; a miss is COUNTED (reason + stage), never silently lost — the
 accounting invariant `frames_in == served + dropped` is part of `stats()`
 and asserted by the CI smoke.
 
-Observability (`repro/obs/`): the per-stage latency distributions and drop
-counters live in the process-wide metrics registry as bounded histograms /
-counters (memory O(1) in clip length — the old per-frame python lists grew
-forever), and with tracing enabled (`obs.trace.enable()`, or `--trace` on
-the benchmarks) every frame carries a root span `frame-<index>` with
-tile/infer/aggregate child spans and EXACTLY one terminal status — "served"
+Observability (`repro/obs/`): the per-stage latency distributions, queue
+waits (`stream_queue_wait_seconds{stage}`: from `_admit` to the stage's
+`get`), the executor hop (`stream_executor_hop_seconds`: from
+`run_in_executor` to `_serve_wave` starting) and drop counters live in the
+process-wide metrics registry as bounded histograms / counters (memory
+O(1) in clip length).  The tile and aggregate stages run inside the
+profiler regions `pipeline.tile` / `pipeline.aggregate`.  With tracing
+enabled (`obs.trace.enable()`, or `--trace` on the benchmarks) every frame
+carries a root span `frame-<index>` with pipeline.tile/infer/
+pipeline.aggregate child spans and EXACTLY one terminal status — "served"
 or "dropped:<stage>/<reason>" — matching the drop ledger, so a shed frame
 carries the span where it died and a served detection explains itself as a
 waterfall.  A deadline miss or a broken ledger trips the flight recorder.
@@ -88,6 +92,7 @@ class StreamConfig:
 class _Item:
     frame: Frame
     t_ingest: float
+    t_admit: float = 0.0                   # last _admit: queue wait start
     tiles: np.ndarray | None = None
     positions: list | None = None
     scores: np.ndarray | None = None
@@ -168,7 +173,11 @@ class StreamingPipeline:
                             for k in _STAGES}
         self._lat_hist = reg.histogram("stream_frame_latency_seconds",
                                        pipe=self._id)
-        self._m_fps = reg.gauge("stream_achieved_fps", pipe=self._id)
+        self._wait_hist = {k: reg.histogram("stream_queue_wait_seconds",
+                                            stage=k, pipe=self._id)
+                           for k in _STAGES}
+        self._hop_hist = reg.histogram("stream_executor_hop_seconds",
+                                       pipe=self._id)
         self._queue_gauges: dict[str, M.Gauge] = {}
         self._t_first: float | None = None
         self._t_last: float | None = None
@@ -207,6 +216,7 @@ class StreamingPipeline:
     async def _admit(self, q: asyncio.Queue, name: str, item: _Item) -> None:
         """Bounded-queue admission: block in throughput mode, apply the drop
         policy in real-time mode (the camera never waits)."""
+        item.t_admit = time.perf_counter()
         if not self.realtime:
             await q.put(item)
         else:
@@ -254,31 +264,33 @@ class StreamingPipeline:
 
     async def _tile_stage(self, q_tile: asyncio.Queue,
                           q_infer: asyncio.Queue) -> None:
-        tr = T.get()
         while True:
             item = await q_tile.get()
             if item is _SENTINEL:
                 await q_infer.put(_SENTINEL)
                 return
+            t0 = time.perf_counter()
+            self._wait_hist["tile"].observe(t0 - item.t_admit)
             if self._expired(item, "tile"):
                 continue
-            t0 = time.perf_counter()
-            child = (tr.start("tile", item.span.trace_id, parent=item.span)
-                     if tr is not None and item.span is not None else None)
-            item.tiles, item.positions = self.tiler.extract(item.frame)
-            if child is not None:
-                tr.end(child, n_tiles=len(item.tiles))
+            with T.region("pipeline.tile", parent=item.span) as child:
+                item.tiles, item.positions = self.tiler.extract(item.frame)
+                if child is not None:
+                    child.tags["n_tiles"] = len(item.tiles)
             item.stage_s["tile"] = time.perf_counter() - t0
             self._stage_hist["tile"].observe(item.stage_s["tile"])
             await self._admit(q_infer, "tile", item)
 
-    def _serve_wave(self, item: _Item) -> "np.ndarray | None":
+    def _serve_wave(self, item: _Item,
+                    t_call: float) -> "np.ndarray | None":
         """One batched wave through the engine/router (worker thread); in
         sweep mode, one jitted full-frame trunk call instead.  The engine's
         intake stays open across waves (continuous batching) and `serve()`
         pops its own results, so the engine's resident state stays O(batch)
         over an unbounded clip.  Returns None when the engine shed any of
-        the frame's tiles — a partially-scored frame is a dropped frame."""
+        the frame's tiles — a partially-scored frame is a dropped frame.
+        `t_call` is when the event loop handed the wave to the executor."""
+        self._hop_hist.observe(time.perf_counter() - t_call)
         eng = self.engine
         if self._disagg:
             try:
@@ -312,16 +324,17 @@ class StreamingPipeline:
             if item is _SENTINEL:
                 await q_agg.put(_SENTINEL)
                 return
+            t0 = time.perf_counter()
+            self._wait_hist["infer"].observe(t0 - item.t_admit)
             if self._expired(item, "infer"):
                 continue
-            t0 = time.perf_counter()
             child = (tr.start("infer", item.span.trace_id, parent=item.span,
                               route=("disagg" if self._disagg
                                      else "sweep" if self.sweep
                                      else "engine"))
                      if tr is not None and item.span is not None else None)
             item.scores = await loop.run_in_executor(
-                None, self._serve_wave, item)
+                None, self._serve_wave, item, time.perf_counter())
             if child is not None:
                 tr.end(child,
                        "ok" if item.scores is not None else "shed")
@@ -338,16 +351,15 @@ class StreamingPipeline:
             item = await q_agg.get()
             if item is _SENTINEL:
                 return
+            t0 = time.perf_counter()
+            self._wait_hist["aggregate"].observe(t0 - item.t_admit)
             if self._expired(item, "aggregate"):
                 continue
-            t0 = time.perf_counter()
-            child = (tr.start("aggregate", item.span.trace_id,
-                              parent=item.span)
-                     if tr is not None and item.span is not None else None)
-            dets = self.tiler.aggregate(item.scores, item.positions,
-                                        item.tiles)
-            if child is not None:
-                tr.end(child, n_detections=len(dets))
+            with T.region("pipeline.aggregate", parent=item.span) as child:
+                dets = self.tiler.aggregate(item.scores, item.positions,
+                                            item.tiles)
+                if child is not None:
+                    child.tags["n_detections"] = len(dets)
             t_done = time.perf_counter()
             item.stage_s["aggregate"] = t_done - t0
             self._stage_hist["aggregate"].observe(item.stage_s["aggregate"])
@@ -392,7 +404,6 @@ class StreamingPipeline:
             by_reason[reason] = by_reason.get(reason, 0) + n
         accounted = frames_in == served + dropped
         fps = served / wall if wall > 0 else 0.0
-        self._m_fps.set(fps)
         lat = self._lat_hist.summary_ms()
         out = {
             "mode": "realtime" if self.realtime else "throughput",

@@ -29,6 +29,7 @@ import numpy as np
 from repro.core import backends as B
 from repro.core import fixed_point as fxp
 from repro.core import smallnet
+from repro.obs import trace as T
 from repro.streaming.sources import Frame
 
 
@@ -106,11 +107,14 @@ class Tiler:
                                          backend=backend))
 
     def _confidences(self, scores: np.ndarray) -> np.ndarray:
-        """Backend-native (N, 10) scores -> float sigmoid confidences."""
-        scores = np.asarray(scores)
-        if np.issubdtype(scores.dtype, np.integer):
-            scores = np.asarray(fxp.from_fixed(jnp.asarray(scores), self.cfg))
-        return scores
+        """Backend-native (N, 10) scores -> float sigmoid confidences.
+        Integer words go to the device and back (`pipeline.confidences`)."""
+        with T.region("pipeline.confidences"):
+            scores = np.asarray(scores)
+            if np.issubdtype(scores.dtype, np.integer):
+                scores = np.asarray(fxp.from_fixed(jnp.asarray(scores),
+                                                   self.cfg))
+            return scores
 
     def confidence_grid(self, scores: np.ndarray,
                         positions: Sequence[tuple[int, int]]) -> np.ndarray:
@@ -147,25 +151,28 @@ class Tiler:
         windows at the default stride collapse) of an accepted detection is
         suppressed regardless of label.  Ties break on (y, x) so the result
         is a pure function of the score words.  Pass `tiles` to apply the
-        `min_mass` foreground gate."""
+        `min_mass` foreground gate.  After the confidences, the host work
+        runs in the profiler region `pipeline.select`."""
         conf = self._confidences(scores)
-        labels = conf.argmax(axis=-1)
-        best = conf.max(axis=-1)
-        if self.min_mass > 0.0 and tiles is not None:
-            mass = self._masses(tiles, positions)
-            best = np.where(mass >= self.min_mass, best, -1.0)
-        hits = [(float(best[i]), positions[i][0], positions[i][1],
-                 int(labels[i]))
-                for i in range(len(positions)) if best[i] >= self.threshold]
-        hits.sort(key=lambda h: (-h[0], h[1], h[2]))
-        out: list[Detection] = []
-        for s, y, x, lab in hits:
-            if any(max(abs(y - d.y), abs(x - d.x)) <= self.min_dist
-                   for d in out):
-                continue
-            out.append(Detection(label=lab, score=s, y=y, x=x,
-                                 size=self.patch))
-        return out
+        with T.region("pipeline.select"):
+            labels = conf.argmax(axis=-1)
+            best = conf.max(axis=-1)
+            if self.min_mass > 0.0 and tiles is not None:
+                mass = self._masses(tiles, positions)
+                best = np.where(mass >= self.min_mass, best, -1.0)
+            hits = [(float(best[i]), positions[i][0], positions[i][1],
+                     int(labels[i]))
+                    for i in range(len(positions))
+                    if best[i] >= self.threshold]
+            hits.sort(key=lambda h: (-h[0], h[1], h[2]))
+            out: list[Detection] = []
+            for s, y, x, lab in hits:
+                if any(max(abs(y - d.y), abs(x - d.x)) <= self.min_dist
+                       for d in out):
+                    continue
+                out.append(Detection(label=lab, score=s, y=y, x=x,
+                                     size=self.patch))
+            return out
 
     def detect(self, params: Any, frame: Frame | np.ndarray, *,
                backend: str | B.Backend = "ref") -> list[Detection]:

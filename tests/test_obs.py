@@ -257,6 +257,54 @@ class TestTracer:
 
 # -- flight recorder ----------------------------------------------------------
 
+class TestRegion:
+    def test_records_into_ring_only_when_enabled(self):
+        with T.region("engine.post") as sp:
+            pass
+        assert sp is None                  # tracing off: profiler only
+        tr = T.enable(capacity=16)
+        with T.region("sweep.score") as top:
+            top.tags["n"] = 1
+            with T.region("sweep.fetch", parent=top) as child:
+                pass
+        spans = tr.recorder.spans()
+        assert [s.name for s in spans] == ["sweep.fetch", "sweep.score"]
+        assert child.parent_id == top.span_id
+        assert child.trace_id == top.trace_id == "sweep.score"
+        assert top.tags == {"n": 1} and top.status == "ok"
+        T.disable()
+        with T.region("engine.post") as sp:
+            pass
+        assert sp is None and len(tr.recorder.spans()) == 2
+
+    def test_marks_errors_and_reraises(self):
+        tr = T.enable(capacity=16)
+        with pytest.raises(ValueError):
+            with T.region("engine.fill", "step-0"):
+                raise ValueError("boom")
+        (s,) = tr.recorder.spans()
+        assert (s.trace_id, s.status) == ("step-0", "error")
+
+
+class TestCompileCounter:
+    def test_counts_new_shapes_not_cached_ones(self):
+        import jax
+        import jax.numpy as jnp
+
+        from repro.core import runtime  # noqa: F401 — registers the listener
+        compiles = M.REGISTRY.counter("jax_compiles")
+        seconds = M.REGISTRY.counter("jax_compile_seconds")
+        f = jax.jit(lambda x: x * 7 + 3)
+        x3, x5 = jnp.ones(3), jnp.ones(5)
+        f(x3).block_until_ready()
+        n, t = compiles.value, seconds.value
+        f(x3).block_until_ready()           # cached: no compile
+        assert compiles.value == n
+        f(x5).block_until_ready()           # a new shape compiles
+        assert compiles.value == n + 1
+        assert seconds.value > t
+
+
 class TestFlightRecorder:
     def _span(self, tr, i):
         return tr.emit("frame", f"f-{i}", float(i), float(i) + 1.0, "served")

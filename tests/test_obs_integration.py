@@ -75,9 +75,10 @@ class TestTracedPipelineReconciles:
     def test_span_taxonomy_present(self, params, clip, tiler, tracer):
         _run_pipeline(params, clip, tiler)
         names = {sp.name for sp in tracer.recorder.spans()}
-        for expected in ("frame", "tile", "infer", "aggregate",
-                         "request", "queue_wait", "batch_form",
-                         "device_step"):
+        for expected in ("frame", "pipeline.tile", "infer",
+                         "pipeline.aggregate", "request", "queue_wait",
+                         "engine.batch_form", "engine.fill",
+                         "engine.device_step", "engine.post"):
             assert expected in names, f"missing {expected!r} spans"
 
     def test_one_frame_root_per_ingested_frame(self, params, clip, tiler,
@@ -95,7 +96,8 @@ class TestTracedPipelineReconciles:
         by_id = {sp.span_id: sp for sp in spans}
         checked = 0
         for sp in spans:
-            if sp.name not in ("tile", "infer", "aggregate"):
+            if sp.name not in ("pipeline.tile", "infer",
+                               "pipeline.aggregate"):
                 continue
             parent = by_id[sp.parent_id]
             assert parent.name == "frame"
@@ -180,6 +182,22 @@ class TestBoundedRetention:
         pipe = _run_pipeline(params, clip, tiler)
         for attr in ("_stage_s", "_latencies", "_lat_s"):
             assert not hasattr(pipe, attr)
+
+
+# -- queue waits and the executor hop -----------------------------------------
+
+class TestQueueWaits:
+    def test_one_wait_per_served_frame_per_stage(self, params, clip, tiler):
+        pipe = _run_pipeline(params, clip, tiler)
+        served = pipe.stats()["frames_served"]
+        assert served == len(clip.frames())
+        for stage in ("tile", "infer", "aggregate"):
+            h = M.REGISTRY.histogram("stream_queue_wait_seconds",
+                                     stage=stage, pipe=pipe._id)
+            assert h.count == served and h.min >= 0.0
+        hop = M.REGISTRY.histogram("stream_executor_hop_seconds",
+                                   pipe=pipe._id)
+        assert hop.count == served and hop.min >= 0.0
 
 
 # -- live-registry export after a real run ------------------------------------
