@@ -34,10 +34,11 @@ disaggregation pattern from the LLM serving world, applied to vision:
     leader to run the trunk; followers block on its completion and are
     counted as `coalesced` — a thundering herd does exactly one trunk pass.
   * Each head replica is a `StageEngine` running the jitted head half
-    (`fcn_sweep.make_head_fn`): quad -> (n_windows, 10) scores through the
-    SAME traced gather + dense head as the monolithic `_sweep_fn`, so
-    cached-path scores are int32 word-exact vs the one-call sweep on the
-    fixed substrates (`benchmarks/stream_table --disagg` gates this).
+    (`fcn_sweep.make_head_fn`): quad -> (n_windows, 10) scores through
+    the SAME traced window slices + dense head as the monolithic
+    `_sweep_fn`, so cached-path scores are int32 word-exact vs the
+    one-call sweep on the fixed substrates (`benchmarks/stream_table
+    --disagg` gates this).
 
 `DisaggServer` fronts the pools with the fleet serving contract the rest
 of the stack expects: bounded intake, per-request deadlines, per-reason

@@ -4,10 +4,11 @@
 window with host-side numpy; this module instead runs smallNet's
 conv->sigmoid->pool->conv->sigmoid->pool trunk over the WHOLE HxW frame in
 one jitted device call per frame (any registered backend, including the
-fused `fixed`/`fixed_pallas` stages), then scores every 28x28 window by
-gathering its 7x7 block of the pooled feature map and applying the 49->10
-dense head — a strided gather + one `fixed_dense`/matmul instead of N
-host-extracted patches.  This is the ZynqNet/Solovyev-style "evaluate the
+fused `fixed`/`fixed_pallas` stages), then scores every 28x28 window
+from its 7x7 block of the pooled feature maps and applies the 49->10 dense
+head — 49 static strided slices (one per window feature, across all
+windows at once) + one `fixed_dense`/matmul instead of N host-extracted
+patches.  This is the ZynqNet/Solovyev-style "evaluate the
 CNN once over the full frame" deployment the ROADMAP called for.
 
 Exactness contract (the reason this file is mostly about padding):
@@ -43,7 +44,10 @@ Edge/geometry contract (validated loudly, tested in tests/test_fcn_sweep.py):
     (two 2x2/2 pools -> stride-4 granularity).  `stride` must be a multiple
     of 4 and the frame must satisfy (H - patch) % 4 == 0 (equivalently
     H % 4 == 0 for patch 28) so the edge-clamped last window of
-    `tile_positions` is gatherable; anything else raises ValueError.
+    `tile_positions` is on the lattice; anything else raises ValueError.
+  * the positions must be the row-major product of evenly stepped row
+    and column starts, each with at most one clamped last start (what
+    `tile_positions` builds): the head reads windows as static slices.
   * `patch` must be a multiple of 4 (the deployed dense head fixes it at
     28: 49 pooled features).
   * saturating fixed-point configs are rejected (saturation is not
@@ -70,11 +74,12 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-from typing import Any, ClassVar, Sequence
+from typing import Any, ClassVar, NamedTuple, Sequence
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import lax
 
 from repro.core import backends as B
 from repro.core import runtime
@@ -212,37 +217,92 @@ def _check_saturation(be: B.Backend) -> None:
             "registered 'fixed'/'fixed_pallas' backends use wraparound mode.")
 
 
-def _window_gather(patch: int, positions: tuple[tuple[int, int], ...]):
-    """Static gather indices + role masks for scoring `positions` from a
-    pooled role-map quad: (rows, cols, is_last_row, is_last_col), where
-    rows/cols are (Nw, k, 1)/(Nw, 1, k) pooled-lattice indices and the
-    masks flag each window feature's last pooled row/col (the role that
-    decides which quad map supplies it)."""
-    k = patch // _POOL
-    gy = jnp.asarray([y // _POOL for y, _ in positions])
-    gx = jnp.asarray([x // _POOL for _, x in positions])
-    off = jnp.arange(k)
-    rows = gy[:, None, None] + off[None, :, None]        # (Nw, k, 1)
-    cols = gx[:, None, None] + off[None, None, :]        # (Nw, 1, k)
-    is_last_row = (off == k - 1)[None, :, None]
-    is_last_col = (off == k - 1)[None, None, :]
-    return rows, cols, is_last_row, is_last_col
+class _Lattice(NamedTuple):
+    """The static window lattice of one sweep on the pooled maps: window
+    side `k` in pooled cells, and per axis the window starts as
+    (first, step, n, tail) -- `n` starts `first + i*step`, then the
+    edge-clamped `tail` start (None where the clamp lands on the step)."""
+    k: int
+    rows: tuple[int, int, int, int | None]
+    cols: tuple[int, int, int, int | None]
 
 
-def _head_scores(be: B.Backend, p: dict, quad, gather, n_windows: int):
+def _axis_starts(starts: list[int]) -> tuple[int, int, int, int | None]:
+    """Frame-pixel window starts along one axis -> the pooled
+    (first, step, n, tail) that `_Lattice` holds."""
+    if any(s % _POOL for s in starts):
+        raise ValueError(
+            f"window starts {starts} are off the stride-{_POOL} pooled "
+            f"lattice")
+    g = [s // _POOL for s in starts]
+    step = g[1] - g[0] if len(g) > 1 else 1
+    body, tail = g, None
+    if len(g) > 2 and g[-1] - g[-2] != step:
+        body, tail = g[:-1], g[-1]
+    if step < 1 or body != list(range(g[0], g[0] + step * len(body), step)) \
+            or (tail is not None and tail <= body[-1]):
+        raise ValueError(
+            f"window starts {starts} are not an evenly stepped lattice "
+            f"with at most one clamped last start")
+    return g[0], step, len(body), tail
+
+
+def _window_lattice(patch: int,
+                    positions: tuple[tuple[int, int], ...]) -> _Lattice:
+    """The static lattice for scoring `positions` from a pooled role-map
+    quad.  `positions` must be the row-major product of its distinct row
+    and column starts, each an evenly stepped run plus at most one
+    clamped last start (what `tile_positions` builds); anything else
+    raises ValueError, as `Tiler.confidence_grid` does."""
+    ys = list(dict.fromkeys(y for y, _ in positions))
+    xs = list(dict.fromkeys(x for _, x in positions))
+    if [tuple(p) for p in positions] != [(y, x) for y in ys for x in xs]:
+        raise ValueError(
+            f"the sweep head needs the row-major product of the window "
+            f"row and column starts: {len(positions)} positions are not "
+            f"{len(ys)} rows x {len(xs)} cols in that order")
+    return _Lattice(patch // _POOL, _axis_starts(ys), _axis_starts(xs))
+
+
+def _take(m, axis: int, starts: tuple[int, int, int, int | None], off: int):
+    """Elements `start + off` of `m` along `axis` for every lattice start:
+    one strided static slice, plus the clamped tail as a one-wide slice."""
+    first, step, n, tail = starts
+    lo = first + off
+    x = lax.slice_in_dim(m, lo, lo + (n - 1) * step + 1, step, axis)
+    if tail is None:
+        return x
+    return jnp.concatenate(
+        [x, lax.slice_in_dim(m, tail + off, tail + off + 1, 1, axis)], axis)
+
+
+def _window_features(quad, lattice: _Lattice):
+    """Role-map quad -> (Nw, k*k) window features, windows row-major and
+    features `dy*k + dx`.  Feature (dy, dx) of every window comes from the
+    one role map its offset selects (C at the corner, B on the last row,
+    R on the last column, I inside), as static slices of that map: no
+    gather, no select."""
+    k, rows, cols = lattice
+    I2, B2, R2, C2 = (_squeeze_map(m) for m in quad)
+    planes = []
+    for dy in range(k):
+        for dx in range(k):
+            m = ((C2 if dx == k - 1 else B2) if dy == k - 1
+                 else (R2 if dx == k - 1 else I2))
+            planes.append(_take(_take(m, 0, rows, dy), 1, cols, dx))
+    # (k*k, Ny, Nx) -> (Ny, Nx, k*k): one transpose after the planes is
+    # cheaper on the TPU v5e than stacking them on the lane axis
+    return jnp.stack(planes).transpose(1, 2, 0).reshape(-1, k * k)
+
+
+def _head_scores(be: B.Backend, p: dict, quad, lattice: _Lattice):
     """The sweep's dense-head half as traced code: role-map quad + static
-    gather -> (Nw, 10) backend-native scores.  Shared verbatim by the
+    lattice -> (Nw, 10) backend-native scores.  Shared verbatim by the
     monolithic `_sweep_fn` and the disaggregated head program
     (`make_head_fn`), so splitting the sweep across engine pools cannot
     change a single word on the integer substrates."""
-    rows, cols, is_last_row, is_last_col = gather
-    I2, B2, R2, C2 = (_squeeze_map(m) for m in quad)
-    feats = jnp.where(
-        is_last_row & is_last_col, C2[rows, cols],
-        jnp.where(is_last_row, B2[rows, cols],
-                  jnp.where(is_last_col, R2[rows, cols],
-                            I2[rows, cols])))            # (Nw, k, k)
-    return smallnet.dense_head(p, feats.reshape(n_windows, -1), backend=be)
+    return smallnet.dense_head(p, _window_features(quad, lattice),
+                               backend=be)
 
 
 @functools.lru_cache(maxsize=64)
@@ -252,12 +312,12 @@ def _sweep_fn(be: B.Backend, frame_shape: tuple[int, int], patch: int,
     """Jitted whole-sweep function for one (backend, geometry): params +
     (1,H,W,1) float frame -> (n_windows, 10) backend-native scores, ONE
     device call per frame."""
-    gather = _window_gather(patch, positions)
+    lattice = _window_lattice(patch, positions)
 
     def run(params, frame):
         p = be.prepare_params(params)
         quad = _trunk_quad(be, p, frame, megakernel)
-        return _head_scores(be, p, quad, gather, len(positions))
+        return _head_scores(be, p, quad, lattice)
 
     return jax.jit(run)
 
@@ -285,16 +345,16 @@ def make_head_fn(backend: str, patch: int,
                  positions: tuple[tuple[int, int], ...]):
     """Jitted HEAD half of the sweep: (params, role-map quad) -> (Nw, 10)
     backend-native window scores for a fixed window lattice.  Runs the
-    SAME traced gather + dense head as the monolithic `_sweep_fn`
+    SAME traced window slices + dense head as the monolithic `_sweep_fn`
     (`_head_scores`), so head-pool scores from a cached feature quad are
     int32 word-exact vs the one-call sweep on the fixed substrates."""
     be = B.get_backend(backend)
     _check_saturation(be)
-    gather = _window_gather(patch, positions)
+    lattice = _window_lattice(patch, positions)
 
     def run(params, quad):
         p = be.prepare_params(params)
-        return _head_scores(be, p, quad, gather, len(positions))
+        return _head_scores(be, p, quad, lattice)
 
     return jax.jit(run)
 

@@ -10,6 +10,8 @@ offline and through the streaming pipeline.  The geometry/edge contract
 (positions on the stride-4 pooled lattice, wraparound-only fixed configs)
 must fail loudly, never approximately.
 """
+import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -17,6 +19,7 @@ from repro.core import backends as B
 from repro.core import fixed_point as fxp
 from repro.core import smallnet
 from repro.serving.vision_engine import VisionEngine
+from repro.streaming import fcn_sweep as fs
 from repro.streaming.fcn_sweep import FcnSweep, sweep_feature_maps
 from repro.streaming.pipeline import StreamingPipeline
 from repro.streaming.sources import SyntheticVideoSource
@@ -253,3 +256,86 @@ def test_confidence_grid_derives_cols_from_positions():
     grid = t.confidence_grid(np.tile(np.linspace(0, 1, 10, dtype=np.float32),
                                      (6, 1)), pos)
     assert grid.shape == (2, 3)
+
+
+# ---------------------------------------------------------------------------
+# window head: static slices of the role maps == the element-wise gather
+# ---------------------------------------------------------------------------
+
+def _gather_features(quad, positions, patch=28):
+    """The head's former formulation, kept as the reference: index each
+    pooled role map with (Nw, k, k) window indices, then pick the map of
+    each feature's role with nested selects."""
+    k = patch // 4
+    gy = jnp.asarray([y // 4 for y, _ in positions])
+    gx = jnp.asarray([x // 4 for _, x in positions])
+    off = jnp.arange(k)
+    rows = gy[:, None, None] + off[None, :, None]
+    cols = gx[:, None, None] + off[None, None, :]
+    last_row = (off == k - 1)[None, :, None]
+    last_col = (off == k - 1)[None, None, :]
+    I, Bm, R, C = (m[0] for m in quad)
+    feats = jnp.where(last_row & last_col, C[rows, cols],
+                      jnp.where(last_row, Bm[rows, cols],
+                                jnp.where(last_col, R[rows, cols],
+                                          I[rows, cols])))
+    return feats.reshape(len(positions), -1)
+
+
+def _random_quad(shape, seed):
+    rng = np.random.default_rng(seed)
+    H, W = shape
+    return tuple(jnp.asarray(rng.integers(np.iinfo(np.int32).min,
+                                          np.iinfo(np.int32).max,
+                                          (1, H // 4, W // 4), np.int32,
+                                          endpoint=True))
+                 for _ in range(4))
+
+
+@pytest.mark.parametrize("stride", (4, 8, 12))
+@pytest.mark.parametrize("shape", ((112, 112), (120, 160), (480, 640)))
+def test_slice_head_equals_gather_head(params, shape, stride):
+    """Every frame/stride pair, clamped tail or none (112 at stride 8 has
+    one, at 4 and 12 none; 120x160 at 12 has one on rows only): the
+    sliced features and the head's scores equal the gather's word for
+    word on random int32 role maps."""
+    pos = tuple(tile_positions(shape, 28, stride))
+    quad = _random_quad(shape, seed=shape[0] + stride)
+    lattice = fs._window_lattice(28, pos)
+    want = _gather_features(quad, pos)
+    got = fs._window_features(quad, lattice)
+    assert got.shape == (len(pos), 49)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    be = B.get_backend("fixed")
+    p = be.prepare_params(params)
+    np.testing.assert_array_equal(
+        np.asarray(fs._head_scores(be, p, quad, lattice)),
+        np.asarray(smallnet.dense_head(p, want, backend=be)))
+
+
+def _lattice(ys, xs):
+    return [(y, x) for y in ys for x in xs]
+
+
+@pytest.mark.parametrize("positions", (
+    _lattice((0, 8), (0, 8))[:-1],                         # a window missing
+    _lattice((0, 8), (0, 8))[::-1],                        # not row-major
+    _lattice((0, 8, 12, 24), (0, 8)),                      # uneven rows
+    _lattice((0, 8), (0, 8, 16, 20, 24)),                  # two tail steps
+    _lattice((0, 6), (0, 8)),                              # off the pool grid
+), ids=("missing", "order", "uneven", "two_tails", "off_grid"))
+def test_slice_head_rejects_non_lattice_positions(positions):
+    with pytest.raises(ValueError):
+        fs._window_lattice(28, tuple(positions))
+
+
+def test_head_program_has_no_gather(params):
+    """The lowered head program at 112x112 reads windows by static slices:
+    a change that brings the element-wise gather back fails here (the
+    reference's own lowering shows the check can see one)."""
+    pos = tuple(tile_positions((112, 112), 28, 8))
+    quad = _random_quad((112, 112), seed=0)
+    head = fs.make_head_fn("fixed_pallas", 28, pos)
+    assert "gather" not in head.lower(params, quad).as_text()
+    assert "gather" in jax.jit(_gather_features, static_argnums=1).lower(
+        quad, pos).as_text()
