@@ -16,6 +16,16 @@ plus optional layout hooks (`ingest`, `flatten`, `fused_conv_act`) for
 substrates whose tensor format differs from NHWC float (the fixed-point
 path carries (B, H, W) int32 words, exactly the Verilog BRAM layout).
 
+Multi-channel graphs (`core/resnet8.py`) use four more primitives, on NHWC
+activations in every backend (float, or int32 words on the fixed ones):
+
+    ingest_channels(images) (B,H,W,C) float images -> activations
+    conv(x, w, b, stride)   pre-activation k x k SAME conv, HWIO weights
+    relu(x)                 max(x, 0)
+    global_avgpool(x)       (B,H,W,C) -> (B,C) mean over H x W
+
+and `accumulate(a, b)` as the residual add.
+
 Registered backends (mirroring TinyCNN/ZynqNet-style swappable layer
 engines over one fixed graph):
 
@@ -57,6 +67,7 @@ from repro.core import runtime
 from repro.kernels.conv2d.ops import conv2d
 from repro.kernels.fixed_conv.ops import (fixed_conv2d, fixed_maxpool2x2,
                                           fixed_sigmoid)
+from repro.kernels.fixed_conv_mc.ops import fixed_conv_mc, im2col, same_padding
 from repro.kernels.frame_trunk.ops import frame_trunk_quad
 from repro.kernels.maxpool2d.ops import maxpool2d
 from repro.kernels.quant_matmul.ops import fixed_dense, quant_matmul
@@ -98,6 +109,18 @@ def maxpool_2x2(x: jnp.ndarray) -> jnp.ndarray:
         x, -jnp.inf, jax.lax.max, (1, 2, 2, 1), (1, 2, 2, 1), "VALID")
 
 
+def conv_same(x: jnp.ndarray, w: jnp.ndarray, b: jnp.ndarray,
+              stride: int) -> jnp.ndarray:
+    """k x k conv with TensorFlow SAME padding, NHWC/HWIO, plus bias."""
+    kh, kw = w.shape[:2]
+    pads = [same_padding(n, k, stride)[1:]
+            for n, k in zip(x.shape[1:3], (kh, kw))]
+    y = jax.lax.conv_general_dilated(
+        x, w, window_strides=(stride, stride), padding=pads,
+        dimension_numbers=("NHWC", "HWIO", "NHWC"), precision=_F32)
+    return y + b
+
+
 # ---------------------------------------------------------------------------
 # Fixed-point primitives (the Verilog datapath, emulated bit-exactly)
 # ---------------------------------------------------------------------------
@@ -118,6 +141,33 @@ def conv_fixed(x: jnp.ndarray, w4: jnp.ndarray, b: jnp.ndarray,
     prods = fxp.fixed_mul(win, w4.reshape(1, 1, 1, 4), cfg)
     acc = jnp.sum(prods, axis=-1, dtype=jnp.int32)        # MAC accumulate
     return fxp.fixed_add(acc, b, cfg)
+
+
+def conv_fixed_mc(x: jnp.ndarray, w: jnp.ndarray, b: jnp.ndarray,
+                  stride: int, cfg: fxp.FixedPointConfig) -> jnp.ndarray:
+    """Multi-channel fixed-point conv: every output word is the MAC array
+    over its k x k x C_in patch (`fixed_matmul`) plus the bias word.
+    x (B,H,W,Cin) int32, w (kh,kw,Cin,Cout) int32, b (Cout,) int32."""
+    kh, kw, cin, cout = w.shape
+    cols, (B, Ho, Wo) = im2col(x, kh, kw, stride)           # (K, M)
+    acc = fxp.fixed_matmul(cols.T, w.reshape(-1, cout), cfg)
+    y = fxp.fixed_add(acc, b.reshape(1, -1), cfg)
+    return y.reshape(B, Ho, Wo, cout)
+
+
+def global_avgpool_fixed(x: jnp.ndarray,
+                         cfg: fxp.FixedPointConfig) -> jnp.ndarray:
+    """(B,H,W,C) words -> (B,C): the int32 (wraparound) sum of the H*W
+    words of a channel, then a right shift by log2(H*W) that rounds as a
+    product does — the pooling unit of a datapath without a divider, so
+    H*W must be a power of two."""
+    n = x.shape[1] * x.shape[2]
+    if n & (n - 1):
+        raise ValueError(f"the shift pool needs a power-of-two extent, "
+                         f"got {x.shape[1]}x{x.shape[2]}")
+    s = jnp.sum(x, axis=(1, 2), dtype=jnp.int32)
+    y = fxp.shift_right_round(s, n.bit_length() - 1, cfg.round_nearest)
+    return fxp._wrap_to_bits(y, cfg.total_bits)
 
 
 def maxpool_fixed(x: jnp.ndarray) -> jnp.ndarray:
@@ -158,6 +208,21 @@ class Backend:
     def sigmoid(self, x):
         return self.sigmoid_fn(x)
 
+    # -- multi-channel primitives (NHWC) -------------------------------------
+    def ingest_channels(self, images):
+        """(B,H,W,C) float images -> NHWC activations, channels kept."""
+        return images
+
+    def conv(self, x, w, b, stride: int = 1):
+        """Pre-activation k x k conv, TensorFlow SAME, HWIO weights."""
+        return conv_same(x, w, b, stride)
+
+    def relu(self, x):
+        return jnp.maximum(x, jnp.zeros((), x.dtype))
+
+    def global_avgpool(self, x):
+        return jnp.mean(x, axis=(1, 2))
+
     # -- layout hooks -------------------------------------------------------
     def params_native(self, params) -> bool:
         """True if `params` are already in this backend's native format."""
@@ -179,8 +244,9 @@ class Backend:
         return self.sigmoid(self.conv2x2_same(x, w, b))
 
     def accumulate(self, a, b):
-        """Add two PRE-ACTIVATION conv partial sums in this backend's word
-        domain.  The FCN frame sweep (streaming/fcn_sweep.py) decomposes a
+        """Add two PRE-ACTIVATION conv partial sums — or a residual branch
+        to its shortcut (core/resnet8.py) — in this backend's word domain.
+        The FCN frame sweep (streaming/fcn_sweep.py) decomposes a
         conv whose taps read from different feature maps into per-map
         masked-weight convs and sums them; for the default float domain
         that's plain `+`, while fixed-point backends override with
@@ -336,6 +402,15 @@ class FixedBackend(Backend):
         # w (2,2,1,1) int32 -> the 4 MAC taps; b (1,) -> scalar bias word
         return conv_fixed(x, w.reshape(4), b[0], self.cfg)
 
+    def ingest_channels(self, images):
+        return fxp.to_fixed(images, self.cfg)             # (B,H,W,C) words
+
+    def conv(self, x, w, b, stride: int = 1):
+        return conv_fixed_mc(x, w, b, stride, self.cfg)
+
+    def global_avgpool(self, x):
+        return global_avgpool_fixed(x, self.cfg)
+
     def maxpool2x2(self, x):
         return maxpool_fixed(x)
 
@@ -417,6 +492,11 @@ class FixedPallasBackend(FixedBackend):
 
     def maxpool2x2(self, x):
         return fixed_maxpool2x2(x, interpret=self.interpret)
+
+    def conv(self, x, w, b, stride: int = 1):
+        # the multi-channel MAC array, one kernels/fixed_conv_mc launch
+        return fixed_conv_mc(x, w, b, stride=stride, cfg=self.cfg,
+                             interpret=self.interpret)
 
     def dense(self, x, w, b):
         return fixed_dense(x, w, b, cfg=self.cfg, interpret=self.interpret)

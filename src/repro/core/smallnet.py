@@ -33,6 +33,8 @@ from repro.core import fixed_point as fxp
 from repro.core import ptq
 from repro.distributed import sharding as shd
 
+IMAGE_SHAPE = (28, 28, 1)
+
 
 def init_params(key: jax.Array) -> dict:
     k1, k2, k3 = jax.random.split(key, 3)

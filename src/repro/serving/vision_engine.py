@@ -6,9 +6,10 @@ single-image classification requests stream into a queue and every `step()`
 forms one batch from WHATEVER is queued at that instant (continuous
 batching: no wave boundaries, no drain/reopen churn), zero-pads it to the
 engine's fixed `batch_size` (one compiled program, no recompilation churn —
-the FIFO depth is the batch size), runs one jitted step of `smallnet.apply`
-on any registered backend, and streams per-request results back with latency
-accounting.
+the FIFO depth is the batch size), runs one jitted step of the model's
+`apply` (smallNet's by default; `model=resnet8` serves ResNet-8's 32x32x3
+images) on any registered backend, and streams per-request results back
+with latency accounting.
 
 Under real load the engine is also the ADMISSION CONTROLLER: `max_queue`
 bounds the intake (an arrival past the bound is shed immediately, reason
@@ -94,7 +95,7 @@ class EngineFaultError(RuntimeError):
 @dataclasses.dataclass
 class VisionRequest:
     uid: int
-    image: np.ndarray                 # (28, 28, 1) float32
+    image: np.ndarray                 # the model's image shape, float32
     t_submit: float = 0.0
     deadline: float | None = None     # absolute perf_counter time, or None
     parent_span: Any = None           # caller's trace context (traced runs)
@@ -122,7 +123,12 @@ class VisionResult:
 
 
 class VisionEngine:
-    """Continuously-batched streaming classifier over any smallNet backend.
+    """Continuously-batched streaming classifier over any backend.
+
+    `model` is the network served: a module (or object) with
+    `apply(params, images, backend=)`, `predict(scores)` and
+    `IMAGE_SHAPE` — `core.smallnet` (the default) or `core.resnet8`.
+    `image_shape` defaults to the model's.
 
     Requests submitted via `submit()` queue up (or are shed at the
     admission bound); each `step()` pops up to `batch_size` of them —
@@ -144,14 +150,16 @@ class VisionEngine:
     """
 
     def __init__(self, params: Any, *, backend: str | B.Backend = "ref",
-                 batch_size: int = 32, image_shape=(28, 28, 1),
+                 batch_size: int = 32, model: Any = None, image_shape=None,
                  warmup: bool = True, mesh: Any = None,
                  max_queue: int | None = None,
                  max_age_ms: float | None = None,
                  default_deadline_ms: float | None = None,
                  min_step_s: float = 0.0):
         self.backend = B.get_backend(backend)
-        self.image_shape = tuple(image_shape)
+        self.model = smallnet if model is None else model
+        self.image_shape = tuple(self.model.IMAGE_SHAPE if image_shape is None
+                                 else image_shape)
         self.mesh = mesh
         self.batch_size = int(batch_size)
         self.max_queue = None if max_queue is None else int(max_queue)
@@ -214,7 +222,7 @@ class VisionEngine:
         be = self.backend
 
         def fwd(p, x):
-            return smallnet.apply(p, x, backend=be)
+            return self.model.apply(p, x, backend=be)
 
         if self.mesh is None:
             return jax.jit(fwd)
@@ -227,7 +235,7 @@ class VisionEngine:
                              in_specs=(P(), self._in_sharding.spec),
                              out_specs=self._out_sharding.spec,
                              check_vma=False)
-        # params replicated (510 params ~ 2 KB; a pytree-prefix sharding
+        # params replicated (smallNet's 510 ~ 2 KB; a pytree-prefix sharding
         # broadcasts to every leaf), batch split across the mesh data axes
         return jax.jit(step,
                        in_shardings=(NamedSharding(self.mesh, P()),
@@ -389,7 +397,7 @@ class VisionEngine:
                                       parent_span=r.parent_span, queued=True)
             raise
         with T.region("engine.post", tid):
-            preds = np.asarray(smallnet.predict(scores))
+            preds = np.asarray(self.model.predict(scores))
             scores_np = np.asarray(scores)
             with self._cond:
                 self._m_busy.inc(t_done - t0)

@@ -21,8 +21,9 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.core import backends as B
 from repro.core import fixed_point as fxp
-from repro.core import runtime, smallnet
+from repro.core import resnet8, runtime, smallnet
 from repro.kernels.fixed_conv.ops import fixed_conv2d, fixed_maxpool2x2
+from repro.kernels.fixed_conv_mc.ops import fixed_conv_mc
 from repro.kernels.frame_trunk import frame_trunk_quad
 from repro.kernels.maxpool2d.ops import maxpool2d
 from repro.kernels.quant_matmul.ops import fixed_dense
@@ -107,3 +108,28 @@ def test_fixed_pallas_sweep_compiles(one_chip):
         (params, jax.ShapeDtypeStruct((1, 112, 112, 1), _F32)))
     text = fn.lower(*args).compile().as_text()
     assert text.count("tpu_custom_call") >= 2    # trunk + dense head
+
+
+@pytest.mark.parametrize("side,cin,cout,k,stride", [
+    (32, 3, 16, 3, 1), (32, 16, 16, 3, 1), (32, 16, 32, 3, 2),
+    (16, 32, 64, 1, 2), (8, 64, 64, 3, 1)])
+def test_fixed_conv_mc_compiles(one_chip, side, cin, cout, k, stride):
+    """ResNet-8's conv layers at batch 32: the stem, a stack-1 conv, the
+    stride-2 3x3 and 1x1 convs, and the widest reduction (K = 576)."""
+    _compile(one_chip,
+             lambda x, w, b: fixed_conv_mc(x, w, b, stride=stride),
+             ((32, side, side, cin), _I32), ((k, k, cin, cout), _I32),
+             ((cout,), _I32))
+
+
+def test_resnet8_step_compiles(one_chip):
+    """The engine's whole ResNet-8 step on fixed_pallas at batch 32: nine
+    conv launches and the dense head."""
+    be = B.get_backend("fixed_pallas")
+    params = jax.eval_shape(resnet8.init_params, jax.random.key(0))
+    args = jax.tree_util.tree_map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip),
+        (params, jax.ShapeDtypeStruct((32, 32, 32, 3), _F32)))
+    fn = jax.jit(lambda p, x: resnet8.apply(p, x, backend=be))
+    text = fn.lower(*args).compile().as_text()
+    assert text.count("tpu_custom_call") == 10
