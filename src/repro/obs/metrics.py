@@ -300,6 +300,12 @@ def parse_prometheus(text: str) -> dict[str, float]:
 # handed an explicit private Registry (tests do, for isolation)
 REGISTRY = Registry()
 
+# the aggregate stage's counter pair (`streaming/tiler.Tiler.aggregate`,
+# one increment each per call): windows thresholded as whole arrays, and the
+# candidates over threshold, the only windows that reach Python
+AGG_WINDOWS = "aggregate_windows"
+AGG_CANDIDATES = "aggregate_candidates"
+
 # unique instance labels so N engines / pipelines / routers coexist in the
 # one process-wide registry without clobbering each other's instruments
 _instance_seq = itertools.count()

@@ -14,9 +14,10 @@ map, word-exact with this tiler on the fixed substrates (the former ROADMAP
 follow-up, landed).
 
 Determinism contract: for integer-scored backends ("fixed"/"fixed_pallas")
-the int32 Qm.n words flow through `from_fixed` — identical words give
-identical floats give identical detections, so the two fixed substrates are
-detection-bit-exact on a frozen clip (asserted in tests).
+the int32 Qm.n words are converted on the host exactly as `from_fixed`
+converts them — identical words give identical floats give identical
+detections, so the two fixed substrates are detection-bit-exact on a frozen
+clip (asserted in tests).
 """
 from __future__ import annotations
 
@@ -29,6 +30,7 @@ import numpy as np
 from repro.core import backends as B
 from repro.core import fixed_point as fxp
 from repro.core import smallnet
+from repro.obs import metrics as M
 from repro.obs import trace as T
 from repro.streaming.sources import Frame
 
@@ -106,15 +108,21 @@ class Tiler:
         return np.asarray(smallnet.apply(params, jnp.asarray(tiles),
                                          backend=backend))
 
+    def _as_float(self, scores: np.ndarray) -> np.ndarray:
+        """Backend-native scores of any shape -> float confidences.  Integer
+        words convert on the host, bit for bit what `fxp.from_fixed` gives:
+        int -> float32 rounds to nearest and `cfg.scale` is a power of two.
+        Float scores pass through."""
+        scores = np.asarray(scores)
+        if np.issubdtype(scores.dtype, np.integer):
+            return scores.astype(np.float32) / np.float32(self.cfg.scale)
+        return scores
+
     def _confidences(self, scores: np.ndarray) -> np.ndarray:
-        """Backend-native (N, 10) scores -> float sigmoid confidences.
-        Integer words go to the device and back (`pipeline.confidences`)."""
+        """Backend-native (N, 10) scores -> float sigmoid confidences, on
+        the host (`pipeline.confidences`)."""
         with T.region("pipeline.confidences"):
-            scores = np.asarray(scores)
-            if np.issubdtype(scores.dtype, np.integer):
-                scores = np.asarray(fxp.from_fixed(jnp.asarray(scores),
-                                                   self.cfg))
-            return scores
+            return self._as_float(scores)
 
     def confidence_grid(self, scores: np.ndarray,
                         positions: Sequence[tuple[int, int]]) -> np.ndarray:
@@ -151,19 +159,33 @@ class Tiler:
         windows at the default stride collapse) of an accepted detection is
         suppressed regardless of label.  Ties break on (y, x) so the result
         is a pure function of the score words.  Pass `tiles` to apply the
-        `min_mass` foreground gate.  After the confidences, the host work
-        runs in the profiler region `pipeline.select`."""
-        conf = self._confidences(scores)
-        with T.region("pipeline.select"):
-            labels = conf.argmax(axis=-1)
-            best = conf.max(axis=-1)
+        `min_mass` foreground gate.
+
+        Whole arrays until the threshold: `pipeline.confidences` converts
+        only each window's maximum word (the conversion is monotone, so it
+        is the maximum confidence); `pipeline.select` thresholds that
+        vector, and only the windows over it reach Python, each labelled by
+        the argmax of its converted row (first index on a tie).  The
+        registry counters `aggregate_windows` / `aggregate_candidates`
+        count both sides; with tracing on, `pipeline.select` carries them
+        as tags.  `scores` is not modified."""
+        with T.region("pipeline.confidences"):
+            scores = np.asarray(scores)
+            best = self._as_float(scores.max(axis=-1))
+        with T.region("pipeline.select") as span:
             if self.min_mass > 0.0 and tiles is not None:
                 mass = self._masses(tiles, positions)
                 best = np.where(mass >= self.min_mass, best, -1.0)
-            hits = [(float(best[i]), positions[i][0], positions[i][1],
-                     int(labels[i]))
-                    for i in range(len(positions))
-                    if best[i] >= self.threshold]
+            cand = np.flatnonzero(best >= self.threshold)
+            labels = self._as_float(scores[cand]).argmax(axis=-1)
+            hits = [(s, positions[i][0], positions[i][1], lab)
+                    for i, s, lab in zip(cand.tolist(), best[cand].tolist(),
+                                         labels.tolist())]
+            M.REGISTRY.counter(M.AGG_WINDOWS).inc(len(best))
+            M.REGISTRY.counter(M.AGG_CANDIDATES).inc(len(cand))
+            if span is not None:
+                span.tags["windows"] = len(best)
+                span.tags["candidates"] = len(cand)
             hits.sort(key=lambda h: (-h[0], h[1], h[2]))
             out: list[Detection] = []
             for s, y, x, lab in hits:

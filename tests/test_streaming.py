@@ -9,15 +9,20 @@ bit-identical detections on a frozen clip.
 import dataclasses
 import time
 
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.core import fixed_point as fxp
 from repro.core import smallnet
+from repro.obs import metrics as M
+from repro.obs import trace as T
 from repro.serving.router import ReplicaRouter
 from repro.serving.vision_engine import VisionEngine
 from repro.streaming.pipeline import StreamConfig, StreamingPipeline
 from repro.streaming.sources import PacedPlayer, SyntheticVideoSource
-from repro.streaming.tiler import Tiler, tile_positions
+from repro.streaming import tiler as tiler_mod
+from repro.streaming.tiler import Detection, Tiler, tile_positions
 
 
 @pytest.fixture(scope="module")
@@ -119,6 +124,144 @@ def test_aggregate_min_mass_gates_empty_windows():
     assert [(d.y, d.x) for d in dets] == [(0, 70)]
     # without tiles the gate is a no-op
     assert len(t.aggregate(scores, pos)) == 2
+
+
+def _loop_aggregate(t, scores, positions, tiles=None):
+    """The per-window aggregate as it was: integer words through
+    `fxp.from_fixed` on the device, then one Python pass over every
+    window.  The plain reference the whole-array aggregate must equal."""
+    scores = np.asarray(scores)
+    if np.issubdtype(scores.dtype, np.integer):
+        scores = np.asarray(fxp.from_fixed(jnp.asarray(scores), t.cfg))
+    labels = scores.argmax(axis=-1)
+    best = scores.max(axis=-1)
+    if t.min_mass > 0.0 and tiles is not None:
+        mass = np.asarray(tiles, np.float32).reshape(len(tiles), -1).mean(1)
+        best = np.where(mass >= t.min_mass, best, -1.0)
+    hits = [(float(best[i]), positions[i][0], positions[i][1], int(labels[i]))
+            for i in range(len(positions)) if best[i] >= t.threshold]
+    hits.sort(key=lambda h: (-h[0], h[1], h[2]))
+    out = []
+    for s, y, x, lab in hits:
+        if any(max(abs(y - d.y), abs(x - d.x)) <= t.min_dist for d in out):
+            continue
+        out.append(Detection(label=lab, score=s, y=y, x=x, size=t.patch))
+    return out
+
+
+_AGG_POS = tile_positions((140, 140), 28, 8)          # 15 x 15 windows
+
+
+def _agg_scores(kind: str, threshold: float, seed: int) -> np.ndarray:
+    """Random (N, 10) scores in `kind`'s format (`q16` / `q8` words or
+    `float`) with the cases the whole-array aggregate must get right:
+    ties in the window maximum and the argmax, scores one step either
+    side of (and on) the threshold, and, for Q16.16, words past 2**24
+    that round to one float."""
+    rng = np.random.default_rng(seed)
+    n = len(_AGG_POS)
+    if kind == "float":
+        s = rng.uniform(0.0, 1.0, (n, 10)).astype(np.float32)
+        thr = np.float32(threshold)
+        near = [np.nextafter(thr, np.float32(-1)), thr,
+                np.nextafter(thr, np.float32(2))]
+    else:
+        cfg = fxp.Q16_16 if kind == "q16" else fxp.Q8_8
+        lo, hi = (-(1 << 31), (1 << 31) - 1) if kind == "q16" \
+            else (-(1 << 15), (1 << 15) - 1)
+        w = int(np.ceil(threshold * cfg.scale))
+        s = rng.integers(-2 * int(cfg.scale), int(cfg.scale), (n, 10),
+                         dtype=np.int64)
+        s[rng.random((n, 10)) < 0.05] = lo
+        near = [w - 1, w, w + 1]
+        if kind == "q16":                     # one float, different words
+            s[:20, 2], s[:20, 5] = 1 << 25, (1 << 25) + 1
+            s[20:30, 7] = hi
+        s = s.astype(np.int32)
+    if threshold > 1e4:                       # no score reaches it
+        near = []
+    for k, v in enumerate(near):              # around the threshold
+        rows = slice(40 + 20 * k, 60 + 20 * k)
+        s[rows, 3] = v
+        s[rows.start:rows.start + 10, 8] = v   # a tie in the argmax
+    s[120:140] = s[100]                       # ties in the window maximum
+    return s
+
+
+@pytest.mark.parametrize("kind", ["q16", "q8", "float"])
+@pytest.mark.parametrize("threshold", [0.9, 58983 / 65536, -1.0, 1e5])
+@pytest.mark.parametrize("mass", [False, True])
+def test_aggregate_matches_the_per_window_loop(kind, threshold, mass):
+    cfg = fxp.Q8_8 if kind == "q8" else fxp.Q16_16
+    t = Tiler(stride=8, threshold=threshold, cfg=cfg,
+              min_mass=0.3 if mass else 0.0)
+    tiles = None
+    if mass:
+        rng = np.random.default_rng(7)
+        tiles = rng.uniform(0.0, 0.6, (len(_AGG_POS), 28, 28, 1)
+                            ).astype(np.float32)
+    cands = M.REGISTRY.counter(M.AGG_CANDIDATES)
+    for seed in range(3):
+        scores = _agg_scores(kind, threshold, seed)
+        kept = scores.copy()
+        want = _loop_aggregate(t, scores, _AGG_POS, tiles)
+        n = cands.value
+        got = t.aggregate(scores, _AGG_POS, tiles)
+        assert got == want
+        np.testing.assert_array_equal(scores, kept)     # not mutated
+        if threshold == -1.0:          # every window, gated ones included
+            assert cands.value - n == len(_AGG_POS) and got
+        if threshold > 1e4:
+            assert cands.value == n and got == []
+
+
+@pytest.mark.parametrize("cfg", [fxp.Q16_16, fxp.Q8_8])
+def test_confidences_equal_from_fixed_bit_for_bit(cfg):
+    rng = np.random.default_rng(1)
+    w = rng.integers(-(1 << 31), (1 << 31) - 1, (500, 10), dtype=np.int64)
+    w[:5] = [[-(1 << 31), (1 << 31) - 1, 0, 1, -1,
+              (1 << 24) + 1, (1 << 25) + 3, -(1 << 24) - 1, 3, 58983]] * 5
+    w = w.astype(np.int32)
+    got = Tiler(cfg=cfg)._confidences(w)
+    want = np.asarray(fxp.from_fixed(jnp.asarray(w), cfg))
+    assert got.dtype == want.dtype == np.float32
+    np.testing.assert_array_equal(got.view(np.uint32), want.view(np.uint32))
+
+
+def test_integer_aggregate_makes_no_device_call(monkeypatch):
+    t = Tiler(stride=8, threshold=0.9)
+    pos = tile_positions((140, 140), 28, 8)
+    words = _agg_scores("q16", 0.9, 0)
+    want = _loop_aggregate(t, words, pos)
+    want_grid = np.asarray(fxp.from_fixed(jnp.asarray(words))).max(-1)
+
+    def refuse(*a, **k):
+        raise AssertionError("device call in the aggregate stage")
+
+    monkeypatch.setattr(tiler_mod.fxp, "from_fixed", refuse)
+    monkeypatch.setattr(tiler_mod.jnp, "asarray", refuse)
+    assert want and t.aggregate(words, pos) == want
+    np.testing.assert_array_equal(t.confidence_grid(words, pos),
+                                  want_grid.reshape(15, 15))
+
+
+def test_aggregate_counts_windows_and_candidates():
+    t = Tiler(stride=8, threshold=0.9)
+    words = np.zeros((len(_AGG_POS), 10), np.int32)
+    words[[3, 50, 51], 4] = 60_000             # 0.9155 > 0.9
+    words[9, 1] = 58_982                       # 0.89999 < 0.9
+    windows = M.REGISTRY.counter(M.AGG_WINDOWS)
+    cands = M.REGISTRY.counter(M.AGG_CANDIDATES)
+    n_w, n_c = windows.value, cands.value
+    tr = T.enable(capacity=16)
+    try:
+        t.aggregate(words, _AGG_POS)
+    finally:
+        T.disable()
+    assert windows.value - n_w == len(_AGG_POS)
+    assert cands.value - n_c == 3
+    (sel,) = [s for s in tr.recorder.spans() if s.name == "pipeline.select"]
+    assert sel.tags == {"windows": len(_AGG_POS), "candidates": 3}
 
 
 # ---------------------------------------------------------------------------
