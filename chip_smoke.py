@@ -11,16 +11,18 @@ device's random-number stream.  Phases on one chip:
   (a) compiled   the interpret default follows the platform, and the
                  fixed_pallas sweep program and engine step lower to Mosaic
                  kernels (`tpu_custom_call`)
-  (b) parity     every registered backend against the float reference
-                 (benchmarks/run.py's parity sweep), fixed vs fixed_pallas
-                 word-equal, and each backend drained through a VisionEngine
+  (b) parity     every registered backend against the float reference on
+                 params with every leaf perturbed (nonzero biases),
+                 fixed vs fixed_pallas word-equal, and each backend
+                 drained through a VisionEngine
   (c) stream     16 synthetic 112x112 frames through the StreamingPipeline
-                 on the fixed_pallas megakernel sweep: every frame served,
-                 window scores and detections identical to the composed
-                 pure-XLA `fixed` sweep, and the first frame's trunk and
-                 score words equal to the golden files
-  (d) seam512    one 512x512 frame, which the megakernel splits into two
-                 tiles: its quad equals the numpy int64 oracle
+                 on the fixed_pallas sweep, whose trunk is one launch:
+                 every frame served, window scores and detections identical
+                 to the host Tiler's patch-wise `fixed` words, and the first
+                 frame's trunk (one-launch and composed) and score words
+                 equal to the golden files
+  (d) seam512    one 512x512 frame, which the one-launch trunk splits into
+                 two tiles: its quad equals the numpy int64 oracle
   (e) serving    a continuously batched fixed_pallas VisionEngine serves
                  256 requests and a ReplicaRouter over ref + fixed_pallas
                  serves 128, with zero sheds and no failover
@@ -64,6 +66,19 @@ def _words_equal(a, b, what: str) -> None:
     _check(n == 0, f"{what}: {n}/{a.size} words differ")
 
 
+def _one_launch_trunk(params, pixels) -> bool:
+    """Whether fixed_pallas takes its one-launch trunk for this frame
+    (traced, not run)."""
+    import jax
+    import numpy as np
+
+    from repro.core import backends as B
+    be = B.get_backend("fixed_pallas")
+    p = be.prepare_params(params)
+    frames = np.asarray(pixels[None], np.float32)
+    return jax.eval_shape(lambda f: be.frame_trunk(f, p), frames) is not None
+
+
 def _load_golden():
     from repro.core import smallnet
     sweep = json.loads((GOLDEN / "sweep_golden.json").read_text())
@@ -92,10 +107,10 @@ def phase_compiled(ctx) -> str:
     _check(runtime.interpret_default() is on_cpu,
            f"interpret default {runtime.interpret_default()} on "
            f"{ctx['platform']}")
-    sweep = FcnSweep(stride=STRIDE, megakernel=True)
+    sweep = FcnSweep(stride=STRIDE)
     pos = tuple(sweep.positions((112, 112)))
     fn = fcn_sweep._sweep_fn(B.get_backend("fixed_pallas"), (112, 112),
-                             sweep.patch, pos, True)
+                             sweep.patch, pos)
     texts = {"sweep": fn.lower(ctx["params"],
                                jnp.zeros((1, 112, 112, 1))).as_text()}
     eng = VisionEngine(ctx["params"], backend="fixed_pallas", batch_size=32,
@@ -109,9 +124,42 @@ def phase_compiled(ctx) -> str:
 
 
 def phase_parity(ctx) -> str:
-    from benchmarks.run import backend_smoke
-    _check(backend_smoke() == 0, "backend parity smoke failed (see above)")
-    return "all backends within tolerance, fixed == fixed_pallas"
+    import jax.numpy as jnp
+    import numpy as np
+
+    from repro.core import backends, smallnet
+    from repro.core import fixed_point as fxp
+    from repro.serving.vision_engine import VisionEngine
+
+    # every leaf nonzero: init_params' zero biases would hide
+    # bias-handling drift
+    params = smallnet.seeded_params()
+    x = jnp.asarray(_images(8))
+    ref = smallnet.apply(params, x, backend="ref")
+    plan = smallnet.apply(params, x, backend="plan")
+    # (comparison target, max-abs-error bound) per backend
+    spec = {"ref": (ref, 0.0), "plan": (plan, 0.0),
+            "pallas": (ref, 1e-4), "pallas_plan": (plan, 1e-4),
+            "fixed": (plan, 5e-3), "fixed_pallas": (plan, 5e-3),
+            "int8": (ref, 0.15)}
+    names = backends.list_backends()
+    _check(set(spec) == set(names), f"backends {names} != {sorted(spec)}")
+    for name in names:
+        scores = smallnet.apply(params, x, backend=name)
+        if scores.dtype == jnp.int32:
+            scores = fxp.from_fixed(scores)
+        want, tol = spec[name]
+        err = float(jnp.abs(scores - want).max())
+        _check(err <= tol, f"{name}: max error {err:.2e} > {tol:g}")
+    _words_equal(smallnet.apply(params, x, backend="fixed_pallas"),
+                 smallnet.apply(params, x, backend="fixed"),
+                 "fixed_pallas vs fixed")
+    for name in names:
+        eng = VisionEngine(params, backend=name, batch_size=4, warmup=False)
+        eng.serve(list(np.asarray(x)))
+        _engine_ok(eng.stats(), 8, f"{name} engine")
+    return (f"{len(names)} backends within tolerance, fixed == "
+            f"fixed_pallas, each engine 8/8")
 
 
 def phase_stream(ctx) -> str:
@@ -119,9 +167,11 @@ def phase_stream(ctx) -> str:
 
     from repro.core import fixed_point as fxp
     from repro.serving.vision_engine import VisionEngine
-    from repro.streaming.fcn_sweep import FcnSweep, sweep_feature_maps
+    from repro.streaming.fcn_sweep import (FcnSweep, composed_feature_maps,
+                                           sweep_feature_maps)
     from repro.streaming.pipeline import StreamingPipeline
     from repro.streaming.sources import SyntheticVideoSource
+    from repro.streaming.tiler import Tiler
 
     params = ctx["params"]
     source = SyntheticVideoSource(n_frames=16, seed=7)
@@ -129,17 +179,20 @@ def phase_stream(ctx) -> str:
     _words_equal(fxp.to_fixed(frames[0].pixels[..., 0]),
                  ctx["sweep_golden"]["inputs"]["frame"],
                  "frame 0 vs golden frame")
+    _check(_one_launch_trunk(params, frames[0].pixels),
+           "the fixed_pallas sweep has no one-launch trunk at 112x112")
     # detection threshold: the 80th percentile of frame 0's confidences, so
     # the clip has real detections to compare
-    comp = FcnSweep(stride=STRIDE, megakernel=False)
-    fb, _ = comp.extract(frames[0])
-    conf = comp._confidences(comp.score(params, fb, backend="fixed"))
+    patchwise = Tiler(stride=STRIDE)
+    tiles, _ = patchwise.extract(frames[0])
+    conf = patchwise._confidences(
+        patchwise.score(params, tiles, backend="fixed"))
     thr = float(np.quantile(conf.max(-1), 0.8))
-    comp = FcnSweep(stride=STRIDE, megakernel=False, threshold=thr)
-    mega = FcnSweep(stride=STRIDE, megakernel=True, threshold=thr)
+    patchwise = Tiler(stride=STRIDE, threshold=thr)
+    sweep = FcnSweep(stride=STRIDE, threshold=thr)
 
     eng = VisionEngine(params, backend="fixed_pallas", warmup=False)
-    pipe = StreamingPipeline(source, eng, mega)          # unpaced
+    pipe = StreamingPipeline(source, eng, sweep)         # unpaced
     results = sorted(pipe.run(), key=lambda r: r.index)
     st = pipe.stats()
     _check(st["frames_in"] == 16 and st["frames_served"] == 16
@@ -149,24 +202,26 @@ def phase_stream(ctx) -> str:
 
     n_det = 0
     for f, r in zip(frames, results):
-        fb, _ = mega.extract(f)
-        _words_equal(mega.score(params, fb, backend="fixed_pallas"),
-                     comp.score(params, fb, backend="fixed"),
+        fb, _ = sweep.extract(f)
+        tiles, _ = patchwise.extract(f)
+        _words_equal(sweep.score(params, fb, backend="fixed_pallas"),
+                     patchwise.score(params, tiles, backend="fixed"),
                      f"frame {f.index} window scores")
-        want = comp.detect(params, f, backend="fixed")
+        want = patchwise.detect(params, f, backend="fixed")
         _check(r.detections == want, f"frame {f.index} detections differ")
         n_det += len(want)
     _check(n_det > 0, "the clip produced no detections")
 
-    maps = sweep_feature_maps(params, frames[0].pixels,
-                              backend="fixed_pallas", megakernel=True)
-    for name, words in maps.items():
-        _words_equal(words, ctx["trunk_golden"]["maps"]["q16_16"][name],
-                     f"trunk {name} vs frame_trunk_golden")
-        _words_equal(words, ctx["sweep_golden"]["maps"][name],
-                     f"trunk {name} vs sweep_golden")
-    fb, _ = mega.extract(frames[0])
-    _words_equal(mega.score(params, fb, backend="fixed_pallas"),
+    for trunk, maps_of in (("one-launch", sweep_feature_maps),
+                           ("composed", composed_feature_maps)):
+        maps = maps_of(params, frames[0].pixels, backend="fixed_pallas")
+        for name, words in maps.items():
+            _words_equal(words, ctx["trunk_golden"]["maps"]["q16_16"][name],
+                         f"{trunk} trunk {name} vs frame_trunk_golden")
+            _words_equal(words, ctx["sweep_golden"]["maps"][name],
+                         f"{trunk} trunk {name} vs sweep_golden")
+    fb, _ = sweep.extract(frames[0])
+    _words_equal(sweep.score(params, fb, backend="fixed_pallas"),
                  ctx["sweep_golden"]["scores"], "window scores vs golden")
     return (f"16/16 frames, {n_det} detections, scores word-exact, "
             f"golden words equal")
@@ -186,8 +241,9 @@ def phase_seam512(ctx) -> str:
     frame = SyntheticVideoSource(n_frames=1, frame_shape=(512, 512),
                                  seed=7).frames()[0]
     be = B.get_backend("fixed_pallas")
-    maps = sweep_feature_maps(ctx["params"], frame.pixels, backend=be,
-                              megakernel=True)
+    _check(_one_launch_trunk(ctx["params"], frame.pixels),
+           "the fixed_pallas sweep has no one-launch trunk at 512x512")
+    maps = sweep_feature_maps(ctx["params"], frame.pixels, backend=be)
     p = be.prepare_params(ctx["params"])
     x = np.asarray(be.ingest(frame.pixels[None]))[0]
     oracle = frame_trunk_quad_ref(

@@ -1,9 +1,9 @@
 """Launches-per-frame accounting: count Pallas kernel dispatches in a jaxpr.
 
-The megakernel PR's whole claim is a launch-topology change — O(stages x
-role-maps) Pallas dispatches per frame collapsing to ONE trunk launch — so
-the perf ledger and the stream_table smoke gate pin the number, not the
-prose.  Counting is static: trace the program with `jax.make_jaxpr` and
+The one-launch trunk (`kernels/frame_trunk`) is a launch-topology change —
+O(stages x role-maps) Pallas dispatches per frame collapsing to ONE trunk
+launch — so `tests/test_frame_trunk.py` pins the number.  Counting is
+static: trace the program with `jax.make_jaxpr` and
 walk every equation (recursing through pjit/scan/cond sub-jaxprs) for the
 `pallas_call` primitive.  This counts launches in the PROGRAM, which under
 jit is exactly launches-per-call; it is mode-independent (interpret vs

@@ -1,48 +1,40 @@
-"""MFU + bytes-moved accounting for smallNet's hot paths.
+"""Analytic MFU + bytes-moved model for smallNet's hot paths.
 
-The paper's headline claims are efficiency numbers (5.1x at 1.5 W), so the
-perf ledger needs more than FPS: every (backend, route) row should say how
-close it runs to the hardware roofline.  This module supplies the two
-halves of that account:
+A closed-form model, not a measurement: the chip benchmark (`chipbench/`)
+measures time, utilization and roofline share on the device.  The module
+holds two halves:
 
   1. a DEVICE DATABASE (`DEVICE_DB`): per-dtype peak FLOP/s + memory
-     bandwidth for the CPU, common GPUs, and TPU generations — the
-     achievable-FLOPs denominator, in the style of PrimeIntellect's
-     `mfu_tracker.py` (device -> generation -> flagship peaks).  Lookups
-     are TOTAL: an unknown accelerator raises with the known-device list
-     (silent zeros would quietly report MFU=inf or 0), while CPU hosts —
-     where Pallas kernels run under the interpreter — always fall back to
-     the generic "cpu" entry (`resolve`).
+     bandwidth for the CPU, common GPUs, and TPU generations, in the style
+     of PrimeIntellect's `mfu_tracker.py` (device -> generation ->
+     flagship peaks).  Lookups are TOTAL: an unknown accelerator raises
+     with the known-device list (silent zeros would quietly report MFU=inf
+     or 0), while CPU hosts — where Pallas kernels run under the
+     interpreter — always fall back to the generic "cpu" entry (`resolve`).
+     `analysis/roofline.py` reads its v5e peaks from here.
 
   2. an ANALYTIC WORKLOAD MODEL (`trunk_workload` / `sweep_workload` /
      `tiler_workload` / `deployed_workload`): model FLOPs and bytes moved
-     per frame for each route the perf ledger rows — the host tiler, the
-     composed quad-cascade sweep, and the `kernels/frame_trunk`
-     megakernel (whose input bytes are the real halo'd HBM->VMEM tile DMA
-     traffic, via `choose_tile`).
+     per frame for each route — the host tiler, the composed quad-cascade
+     sweep, and the `kernels/frame_trunk` one-launch trunk (whose input
+     bytes are the real halo'd HBM->VMEM tile DMA traffic, via
+     `choose_tile`).
 
-MFU denominator convention (documented in README §Observability): the
-numerator is MODEL FLOPs — 2 flops per multiply-accumulate of the convs
-and dense layers the route's algorithm specifies, padding taps included
-(the datapath multiplies them against real zero operands), activations /
-bias adds / pool comparisons excluded — NOT the HLO instruction count.
-`tests/test_mfu.py` cross-checks the model against `analysis/hlo_parse.py`
-conv FLOPs on the XLA-visible ref path; Pallas launches are opaque to HLO,
-which is exactly why the denominator is analytic.
+FLOP convention: MODEL FLOPs — 2 flops per multiply-accumulate of the
+convs and dense layers the route's algorithm specifies, padding taps
+included (the datapath multiplies them against real zero operands),
+activations / bias adds / pool comparisons excluded — NOT the HLO
+instruction count (Pallas launches are opaque to HLO).
 
 Bytes-moved convention: off-chip traffic between kernel launches.  The
 composed sweep round-trips every intermediate role map through HBM (each
-launch reads its inputs and writes its outputs), the megakernel moves only
-the halo'd input tiles in and the pooled quad out — that asymmetry, not
-FLOPs, is what the one-launch trunk actually buys, and `achieved_bw`
-makes it visible in the ledger.
+launch reads its inputs and writes its outputs), the one-launch trunk
+moves only the halo'd input tiles in and the pooled quad out.
 
-MFU clock convention (`mfu_clock`): on real accelerators, the measured
-wall time of the route's jitted per-frame program; under interpret-mode
-emulation (every CPU CI host), the roofline floor `modeled_seconds` —
-emulator wall time is not a device clock, and the floor keeps committed
-ledger MFU deterministic across machines.  Every ledger row records which
-basis produced its mfu.
+Clock convention (`mfu_clock`): on real accelerators, a measured wall
+time; under interpret-mode emulation, the roofline floor
+`modeled_seconds` — emulator wall time is not a device clock.  The basis
+string says which one produced a value.
 """
 from __future__ import annotations
 
@@ -330,12 +322,12 @@ ROUTE_WORKLOADS = ("tiler", "sweep_composed", "sweep_megakernel")
 
 def route_workload(route: str, H: int, W: int, n_windows: int,
                    word_bytes: int = 4) -> Workload:
-    """The perf-ledger entry point: one Workload per (route, geometry)."""
+    """One Workload per (route, geometry)."""
     if route == "tiler":
         return tiler_workload(n_windows, word_bytes)
     if route in ("sweep_composed", "sweep_megakernel"):
         return sweep_workload(H, W, n_windows, route, word_bytes)
-    raise ValueError(f"unknown ledger route {route!r} "
+    raise ValueError(f"unknown route {route!r} "
                      f"(known: {ROUTE_WORKLOADS})")
 
 
@@ -357,8 +349,7 @@ def mfu(workload: Workload, seconds: float, *, device: DeviceSpec,
     """Model-FLOPs utilization: (model FLOPs / wall seconds) / peak FLOP/s
     of the device at the backend's dtype class.  By construction in (0, 1]
     for any real measurement — a value outside that range means the
-    workload model or the device entry is wrong, and the ledger gate
-    treats it as a failure, not a triumph."""
+    workload model or the device entry is wrong."""
     return achieved(workload, seconds)["achieved_flops"] / device.peak(dtype)
 
 
@@ -371,9 +362,8 @@ def modeled_seconds(workload: Workload, *, device: DeviceSpec,
     emulator's clock, round-tripping 2 MB through HBM costs the same as
     DMAing 100 KB once, which would invert every conclusion the bytes
     model exists to surface.  The roofline floor is deterministic and
-    machine-independent, so ledger MFU gates stay reproducible on any CI
-    host; on real accelerators the measured clock is used instead
-    (`mfu_clock`)."""
+    machine-independent; on real accelerators the measured clock is used
+    instead (`mfu_clock`)."""
     t = roofline_terms(workload, device=device, dtype=dtype)
     return max(t["compute_s"], t["memory_s"])
 
@@ -383,8 +373,8 @@ def mfu_clock(workload: Workload, measured_s: float, *, device: DeviceSpec,
     """(seconds, basis) the MFU/achieved-rate columns divide by: the
     measured device-program wall time on real hardware, the roofline floor
     (`modeled_seconds`) under interpret-mode emulation.  The basis string
-    ("measured" / "roofline_model") is committed next to every mfu value
-    so a ledger row can never be misread as a hardware measurement."""
+    ("measured" / "roofline_model") travels with every mfu value so a
+    modelled one can never be misread as a hardware measurement."""
     if interpret:
         return modeled_seconds(workload, device=device, dtype=dtype), \
             "roofline_model"

@@ -29,7 +29,7 @@ from repro.configs.base import ArchConfig, SHAPES, ShapeSpec, get_config
 
 # --- TPU v5e hardware constants (per chip) ---------------------------------
 # The per-chip peaks live in the MFU device database (analysis/mfu.py) so
-# the LLM roofline and the smallNet perf ledger can never disagree about
+# the LLM roofline and the smallNet workload model can never disagree about
 # what the hardware can do; these module-level names remain the v5e view
 # this three-term model is calibrated for.
 _V5E = DEVICE_DB["tpu-v5e"]
@@ -212,33 +212,3 @@ def to_dict(r: Roofline) -> dict:
     d["step_time_s"] = r.step_time_s
     d["roofline_fraction"] = r.roofline_fraction
     return d
-
-
-def smallnet_rooflines(*, device_name: str = "tpu-v5e", H: int = 112,
-                       W: int = 112, stride: int = 8) -> dict[str, dict]:
-    """Analytic two-term rooflines for smallNet's actual hot paths — the
-    perf-ledger routes (host tiler / composed sweep / megakernel sweep)
-    plus the deployed single-image cell — on one device from the MFU
-    database.  No compilation: the workload model (analysis/mfu.py) is
-    closed-form, so this runs in microseconds and the bench-smoke lane can
-    gate it on every push (NaN or zero-denominator here means the model or
-    a device entry broke)."""
-    from repro.analysis import mfu
-    from repro.streaming.tiler import tile_positions
-
-    if device_name not in DEVICE_DB:
-        raise KeyError(f"unknown device {device_name!r} "
-                       f"(known: {sorted(DEVICE_DB)})")
-    dev = DEVICE_DB[device_name]
-    n_windows = len(tile_positions((H, W), mfu.PATCH, stride))
-    out: dict[str, dict] = {}
-    for backend in ("ref", "fixed_pallas"):
-        dtype, wb = mfu.backend_numerics(backend)
-        for route in mfu.ROUTE_WORKLOADS:
-            wl = mfu.route_workload(route, H, W, n_windows, wb)
-            out[f"smallnet-{backend}|{route}"] = mfu.roofline_terms(
-                wl, device=dev, dtype=dtype)
-    dtype, wb = mfu.backend_numerics("fixed_pallas")
-    out["smallnet-fixed_pallas|deployed"] = mfu.roofline_terms(
-        mfu.deployed_workload(wb), device=dev, dtype=dtype)
-    return out

@@ -85,8 +85,7 @@ def evaluate_all_paths(params: dict, n_test: int = 2000, seed: int = 1) -> dict:
 def measure_latency(apply_fn: Callable, params: Any, batch: int = 1,
                     iters: int = 50) -> float:
     """Wall-clock per-inference latency (seconds) on this host (the paper's
-    'software inference time' analogue; its HW number maps to the TPU
-    roofline estimate in benchmarks/latency_table.py)."""
+    'software inference time' analogue)."""
     x = jnp.zeros((batch, 28, 28, 1), jnp.float32)
     fn = jax.jit(lambda p, x: apply_fn(p, x))
     fn(params, x).block_until_ready()

@@ -2,7 +2,7 @@
 
 Every Pallas wrapper in kernels/*/ops.py takes `interpret: bool | None`
 and resolves `None` against this module's default, so the whole stack
-(backend registry -> FcnSweep -> StreamingPipeline -> benchmarks) moves
+(backend registry -> FcnSweep -> StreamingPipeline -> engines) moves
 between the CPU interpreter and compiled TPU kernels together.
 
 The default comes from the platform: the Pallas interpreter on a CPU

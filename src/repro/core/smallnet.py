@@ -53,7 +53,7 @@ def param_count(params: dict) -> int:
 def seeded_params(seed: int = 0, noise: float = 0.1) -> dict:
     """Deterministic params with every leaf nonzero (`init_params` zeroes
     the biases, which would flatten any confidence landscape): the
-    no-training stand-in shared by the streaming benchmarks, the golden
+    no-training stand-in shared by the backend parity tests, the golden
     generators, and the frozen-clip test batteries — ONE definition, so a
     recipe tweak cannot silently desynchronize what those gates pin."""
     params = init_params(jax.random.key(seed))

@@ -24,14 +24,13 @@ conservative: the TPU compiler (AOT for a described v5e) accepts tiles the
 model rejects, 512x512 and 1024x512 among them, under its default scoped
 VMEM limit, because it does not keep every map resident at once.
 
-The perf ledger's bytes-moved account (`analysis/mfu.py`,
-`trunk_workload(..., "sweep_megakernel")`) counts this kernel's off-chip
-traffic from the same geometry: n_tiles x (th+HALO)(tw+HALO) input words
-DMA'd HBM->VMEM (the halo apron is genuinely re-read at tile seams) plus
-the 4 x (H/4)(W/4) output quad written back — nothing else leaves the
-core, which is exactly the ~20x byte reduction over the composed sweep's
-per-launch HBM round-trips that the ledger's `mfu`/`achieved_bw` columns
-surface.  `tests/test_mfu.py` pins the model to `choose_tile`/`HALO`.
+Off-chip traffic follows from the same geometry: n_tiles x
+(th+HALO)(tw+HALO) input words DMA'd HBM->VMEM (the halo apron is
+genuinely re-read at tile seams) plus the 4 x (H/4)(W/4) output quad
+written back — nothing else leaves the core, about 20x fewer bytes than
+the composed sweep's per-launch HBM round-trips.  `analysis/mfu.py`
+(`trunk_workload(..., "sweep_megakernel")`) models this count from
+`choose_tile`/`HALO`.
 
 Geometry contract (loud, tested in tests/test_frame_trunk_props.py): the
 frame must have H % 4 == W % 4 == 0 and be at least 4x4 — the same pooled

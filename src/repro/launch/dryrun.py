@@ -20,7 +20,7 @@ Usage:
     python -m repro.launch.dryrun --arch llama3-405b --shape train_4k --mesh single_pod
     python -m repro.launch.dryrun --hlo-dir /tmp/hlo   # also dump HLO text
 
-Per-cell results append to benchmarks/dryrun_results.json (idempotent:
+Per-cell results append to dryrun_results.json at the checkout root (idempotent:
 already-recorded OK cells are skipped unless --force).
 """
 import argparse
@@ -37,7 +37,7 @@ from repro.configs.base import ARCH_IDS, SHAPES, get_config
 from repro.launch.lowering import lower_cell, cell_report
 from repro.launch.mesh import make_production_mesh
 
-RESULTS = pathlib.Path(__file__).resolve().parents[3] / "benchmarks" / "dryrun_results.json"
+RESULTS = pathlib.Path(__file__).resolve().parents[3] / "dryrun_results.json"
 
 
 def load_results() -> dict:

@@ -1,7 +1,7 @@
 """Cell lowering: (arch x shape x mesh) -> lowered/compiled artifacts + analysis.
 
-Shared by launch/dryrun.py (the deliverable), analysis/roofline.py and
-benchmarks/.  Never sets XLA flags itself — the caller controls device count.
+Shared by launch/dryrun.py (the deliverable) and analysis/roofline.py.
+Never sets XLA flags itself — the caller controls device count.
 """
 from __future__ import annotations
 

@@ -16,8 +16,7 @@ module replaces them:
               reservoir the reported percentiles are exact.
 
   Registry    process-wide get-or-create by (name, labels); the default
-              `REGISTRY` is what the benchmarks' `--trace` Prometheus dump
-              exports.  Components label their instruments with a unique
+              `REGISTRY` is what `recorder.dump_prometheus` exports.  Components label their instruments with a unique
               instance label so fleets of engines coexist in one registry.
 
 Percentile convention (the ONE shared helper): `percentile(xs, q)` is

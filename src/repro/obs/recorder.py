@@ -11,15 +11,15 @@ itself to JSONL when something goes wrong:
 
 Trips are rate-limited per reason (`trip_limit` dumps each; the first
 failures are the diagnosable ones, the ten-thousandth is noise) and write
-`flight_<reason>_<n>.jsonl` under `dump_dir` (default: cwd).  `stream_table
---trace` also dumps the ring unconditionally at end of run — the committed
-observability artifact next to `BENCH_<pr>.json`.
+`flight_<reason>_<n>.jsonl` under `dump_dir` (default: cwd).
+`examples/stream_demo.py --trace` also dumps the ring unconditionally at
+end of run.
 
 Dump format: one span per line (see `trace.Span.to_dict`), sorted by
 `t_start`, preceded by one header line `{"flight_recorder": {...}}` with
 the dump reason/detail/capacity.  `load_jsonl` round-trips it.
 
-`reconcile()` is the span/ledger cross-check the CI trace smoke gates on:
+`reconcile()` is the span/ledger cross-check:
 every root span ends in exactly ONE terminal state, terminal counts equal
 the component ledger's served/dropped/shed counters, and clocks are sane
 (end >= start, children nested inside their parent's window).
@@ -118,7 +118,7 @@ def load_jsonl(path: str) -> tuple[dict, list[Span]]:
 
 def dump_prometheus(path: str, registry=None) -> str:
     """Write the registry's Prometheus text exposition next to the trace
-    dump (the other half of the `--trace` artifact pair)."""
+    dump."""
     from repro.obs import metrics
     reg = registry if registry is not None else metrics.REGISTRY
     os.makedirs(os.path.dirname(path) or ".", exist_ok=True)

@@ -4,8 +4,8 @@ A `Span` is one timed region on the monotonic clock (`perf_counter` — the
 same clock every latency number in the repo is measured on, so span
 durations and stats() latencies are directly comparable): name, trace id
 (which frame / request it belongs to), span id + parent id (nesting),
-tags, and a TERMINAL STATUS.  The status convention is the contract the
-CI trace smoke reconciles against the serving ledgers:
+tags, and a TERMINAL STATUS.  The status convention is the contract
+`recorder.reconcile` checks against the serving ledgers:
 
   root spans ("frame", "request") end in exactly one terminal state —
   "served", "dropped:<stage>/<reason>", or "shed:<reason>" — matching the
@@ -17,9 +17,9 @@ CI trace smoke reconciles against the serving ledgers:
 Tracing is OFF by default and costs one `trace.get()` (a module attribute
 read) + None check per instrumentation site until `trace.enable()` turns
 it on; enabling installs a process-wide `Tracer` whose finished spans land
-in a bounded `recorder.FlightRecorder` ring.  The `--trace` flag on
-`stream_table` / `goodput_table` / `stream_demo` is a thin wrapper around
-`enable()` + a JSONL dump of the ring.
+in a bounded `recorder.FlightRecorder` ring.  The `--trace` flag of
+`examples/stream_demo.py` is a thin wrapper around `enable()` + a JSONL
+dump of the ring.
 
 `region(name)` is the span for synchronous work on one thread.  It always
 enters a `jax.profiler.TraceAnnotation(name)` — a no-op without a profiler

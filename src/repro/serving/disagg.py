@@ -18,15 +18,15 @@ disaggregation pattern from the LLM serving world, applied to vision:
     frames ──> TRUNK POOL ──> FeatureMapCache ──> HEAD POOL ──> scores
                (N heavy          (bounded LRU+TTL,   (M cheap
                 replicas,         single-flight)      replicas)
-                megakernel-
-                capable)
+                one-launch
+                trunk)
 
   * Each trunk replica is a `StageEngine` running the jitted trunk half of
     the sweep (`fcn_sweep.make_trunk_fn`: one launch per frame on the
     fixed substrates via the frame_trunk megakernel) — the level-2 role-map
     quad (I, B, R, C) in the backend's native word domain.
   * The `FeatureMapCache` holds recent quads keyed on (frame digest,
-    backend, fixed-point config, megakernel route, interpret mode) — every
+    backend, fixed-point config, interpret mode) — every
     axis that changes the words changes the key, so a cached quad can NEVER
     be served to a query it isn't bit-exact for.  LRU + optional TTL keep
     memory bounded; hits/misses/evictions are registry counters.
@@ -37,8 +37,8 @@ disaggregation pattern from the LLM serving world, applied to vision:
     (`fcn_sweep.make_head_fn`): quad -> (n_windows, 10) scores through
     the SAME traced window slices + dense head as the monolithic
     `_sweep_fn`, so cached-path scores are int32 word-exact vs the
-    one-call sweep on the fixed substrates (`benchmarks/stream_table
-    --disagg` gates this).
+    one-call sweep on the fixed substrates (`tests/test_disagg.py` pins
+    this).
 
 `DisaggServer` fronts the pools with the fleet serving contract the rest
 of the stack expects: bounded intake, per-request deadlines, per-reason
@@ -49,8 +49,7 @@ on a healthy sibling), and the no-silent-loss ledger
 
 Both call styles are supported: synchronous `score_frame()` (what
 `StreamingPipeline` drives per frame) and open-loop `submit()` + `wait()`
-+ `pop_results()` (what the goodput harness replays arrival schedules
-against) — trunk and head replica counts scale independently under either.
++ `pop_results()` (for replaying open-loop arrival schedules) — trunk and head replica counts scale independently under either.
 
 When to prefer this over the monolithic `FcnSweep`: repeated or
 overlapping queries per frame (cache hits skip the trunk entirely),
@@ -114,26 +113,21 @@ def _cfg_token(be: B.Backend) -> str:
 class FeatureMapKey:
     """Everything that determines the trunk's output words for one frame.
 
-    `digest` pins the pixels; `backend`/`cfg` pin the word domain;
-    `megakernel` pins the trunk route (None/True/False produce identical
-    words on the fixed substrates, but the key keeps them separate so a
-    route-comparison harness never reads the other route's words as its
-    own); `interpret` pins the process-wide interpret switch (compiled and
+    `digest` pins the pixels; `backend`/`cfg` pin the word domain (the
+    backend also fixes the trunk route); `interpret` pins the process-wide interpret switch (compiled and
     interpreted programs are bit-identical for the integer substrates, but
     the switch also invalidates jit caches — keying on it makes cache
     entries exactly as durable as the programs that made them)."""
     digest: str
     backend: str
     cfg: str
-    megakernel: bool | None
     interpret: bool
 
 
-def feature_key(frame: np.ndarray, be: B.Backend,
-                megakernel: bool | None) -> FeatureMapKey:
+def feature_key(frame: np.ndarray, be: B.Backend) -> FeatureMapKey:
     return FeatureMapKey(
         digest=frame_digest(frame), backend=be.name, cfg=_cfg_token(be),
-        megakernel=megakernel, interpret=bool(runtime.interpret_default()))
+        interpret=bool(runtime.interpret_default()))
 
 
 # ---------------------------------------------------------------------------
@@ -701,8 +695,8 @@ class DisaggServer:
     the topology).  Pipeline-compatible: exposes `.params` / `.backend` /
     `.score_frame(frames)` so `StreamingPipeline` can drive it exactly
     where it drives the monolithic sweep, and the open-loop
-    `submit`/`wait`/`pop_results`/`stats` contract so the goodput harness
-    can replay arrival schedules against it.
+    `submit`/`wait`/`pop_results`/`stats` contract for replaying
+    open-loop arrival schedules against it.
 
     Dispatch is least-loaded over each pool with trunk failover: a query
     whose trunk request dies on a faulted replica retries on the next
@@ -715,7 +709,6 @@ class DisaggServer:
                  backend: str | B.Backend = "fixed",
                  frame_shape: tuple[int, int] = (112, 112),
                  patch: int = 28, stride: int = 8,
-                 megakernel: bool | None = None,
                  n_trunk: int = 2, n_head: int = 1,
                  cache_capacity: int = 64, cache_ttl_s: float | None = None,
                  cache: FeatureMapCache | None = None,
@@ -732,15 +725,13 @@ class DisaggServer:
         self.frame_shape = tuple(frame_shape)
         self.patch = int(patch)
         self.stride = int(stride)
-        self.megakernel = megakernel
         self.default_deadline_ms = (None if default_deadline_ms is None
                                     else float(default_deadline_ms))
         self.max_queue = None if max_queue is None else int(max_queue)
         # the window lattice is the sweep's own (geometry contract included)
-        sweep = fs.FcnSweep(patch=self.patch, stride=self.stride,
-                            megakernel=megakernel)
+        sweep = fs.FcnSweep(patch=self.patch, stride=self.stride)
         self.positions = tuple(sweep.positions(self.frame_shape))
-        self._trunk_fn = fs.make_trunk_fn(self.backend.name, megakernel)
+        self._trunk_fn = fs.make_trunk_fn(self.backend.name)
         self._head_fn = fs.make_head_fn(self.backend.name, self.patch,
                                         self.positions)
         self.cache = (cache if cache is not None
@@ -843,7 +834,7 @@ class DisaggServer:
     def _trunk_quad(self, frames: np.ndarray, deadline: float | None,
                     parent_span: Any) -> tuple[Any, bool]:
         """(quad, cache_hit) through the cache's single-flight path."""
-        key = feature_key(frames, self.backend, self.megakernel)
+        key = feature_key(frames, self.backend)
         hit = True
 
         def compute():
@@ -909,7 +900,7 @@ class DisaggServer:
         self._record_served(uid, scores, t0, deadline, hit, parent_span)
         return scores
 
-    # -- open-loop interface (what the goodput harness drives) --------------
+    # -- open-loop interface ------------------------------------------------
 
     def submit(self, image: np.ndarray, *, deadline_ms: float | None = None,
                t_submit: float | None = None,
@@ -1157,7 +1148,7 @@ class DisaggServer:
                     f"pending={pending}")
         return out
 
-    # -- detection-parity helper (benchmarks, tests) ------------------------
+    # -- detection-parity helper -------------------------------------------
 
     def detect(self, frame: "Frame | np.ndarray", *,
                tiler: fs.FcnSweep | None = None) -> list:
@@ -1168,8 +1159,7 @@ class DisaggServer:
         `tiler` being compared against to share its threshold/dedup
         settings; the default matches `FcnSweep`'s defaults."""
         sweep = tiler if tiler is not None else fs.FcnSweep(
-            patch=self.patch, stride=self.stride,
-            megakernel=self.megakernel)
+            patch=self.patch, stride=self.stride)
         px = frame.pixels if isinstance(frame, Frame) else np.asarray(frame)
         if px.ndim == 2:
             px = px[..., None]

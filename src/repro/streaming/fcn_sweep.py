@@ -58,10 +58,12 @@ Edge/geometry contract (validated loudly, tested in tests/test_fcn_sweep.py):
 Launch topology: the composed cascade dispatches O(stages x role-maps)
 kernel launches per frame on the Pallas substrates (4 single-source + 5
 mixed-source convs at level 1, plus pools and PLAN units).  On the fixed
-substrates the whole quad trunk now also exists as ONE tiled Pallas launch
-(`kernels/frame_trunk`), reached through `Backend.frame_trunk`; the
-`megakernel` knob below picks the route, and `benchmarks/perf_ledger.py`
-pins launches-per-frame for both.
+substrates the whole quad trunk also exists as ONE tiled Pallas launch
+(`kernels/frame_trunk`), reached through `Backend.frame_trunk`.  The
+backend picks the route from the frame: `_trunk_quad` takes the one-launch
+trunk wherever the hook returns one (fixed substrates, single frames with
+extents a multiple of 4, wraparound words) and the composed cascade,
+`composed_trunk_quad`, everywhere else.
 
 `FcnSweep` is `Tiler`-compatible: `positions` / `extract` / `score` /
 `confidence_grid` / `aggregate` / `detect` have the same shapes and
@@ -179,32 +181,25 @@ def _squeeze_map(x):
     return x[0, ..., 0] if x.ndim == 4 else x[0]
 
 
-def _trunk_quad(be: B.Backend, p: dict, frames, megakernel: bool | None = None):
-    """Both conv stages of the sweep over one (1,H,W,1) float frame batch:
-    the level-2 role-map quad (I, B, R, C), each (1, H/4, W/4[, 1]).  The
-    single trunk definition shared by the jitted scorer and the
-    golden-pinned `sweep_feature_maps` view.
-
-    `megakernel` routes through the backend's whole-frame `frame_trunk`
-    hook (kernels/frame_trunk: the entire quad trunk in ONE Pallas launch
-    on the fixed substrates): None tries the hook and falls back to the
-    composed per-stage path, True requires it (raising where no megakernel
-    exists), False forces the composed path (what the megakernel's
-    word-exactness gates compare against)."""
-    if megakernel is None or megakernel:
-        quad = be.frame_trunk(frames, p)
-        if quad is not None:
-            return quad
-        if megakernel:
-            raise NotImplementedError(
-                f"backend {be.name!r} has no frame_trunk megakernel for "
-                f"frames of shape {tuple(frames.shape)} (the one-launch "
-                f"trunk exists on the fixed substrates, for single "
-                f"multiple-of-4 frames)")
+def composed_trunk_quad(be: B.Backend, p: dict, frames):
+    """Both conv stages of the sweep as the composed per-stage cascade over
+    one (1,H,W,1) float frame batch: the level-2 role-map quad (I, B, R,
+    C), each (1, H/4, W/4[, 1]).  The route of every backend without a
+    whole-frame `frame_trunk`, and the reference the one-launch trunk
+    must match word for word."""
     x = be.ingest(frames)
     quad = (x, x, x, x)      # pixels are role-independent at level 0
     quad = _sweep_stage(be, quad, p["conv1"]["w"], p["conv1"]["b"])
     return _sweep_stage(be, quad, p["conv2"]["w"], p["conv2"]["b"])
+
+
+def _trunk_quad(be: B.Backend, p: dict, frames):
+    """The sweep's trunk: the backend's one-launch `frame_trunk` where it
+    has one for these frames, else `composed_trunk_quad`.  The single
+    trunk definition shared by the jitted scorer and the golden-pinned
+    `sweep_feature_maps` view."""
+    quad = be.frame_trunk(frames, p)
+    return quad if quad is not None else composed_trunk_quad(be, p, frames)
 
 
 def _check_saturation(be: B.Backend) -> None:
@@ -307,8 +302,7 @@ def _head_scores(be: B.Backend, p: dict, quad, lattice: _Lattice):
 
 @functools.lru_cache(maxsize=64)
 def _sweep_fn(be: B.Backend, frame_shape: tuple[int, int], patch: int,
-              positions: tuple[tuple[int, int], ...],
-              megakernel: bool | None = None):
+              positions: tuple[tuple[int, int], ...]):
     """Jitted whole-sweep function for one (backend, geometry): params +
     (1,H,W,1) float frame -> (n_windows, 10) backend-native scores, ONE
     device call per frame."""
@@ -316,26 +310,26 @@ def _sweep_fn(be: B.Backend, frame_shape: tuple[int, int], patch: int,
 
     def run(params, frame):
         p = be.prepare_params(params)
-        quad = _trunk_quad(be, p, frame, megakernel)
+        quad = _trunk_quad(be, p, frame)
         return _head_scores(be, p, quad, lattice)
 
     return jax.jit(run)
 
 
 @functools.lru_cache(maxsize=32)
-def make_trunk_fn(backend: str, megakernel: bool | None = None):
+def make_trunk_fn(backend: str):
     """Jitted TRUNK half of the sweep for a registered backend: (params,
     (1,H,W,1) float frame) -> the level-2 role-map quad (I, B, R, C) in
     the backend's native domain.  This is the heavy per-frame stage the
     disaggregated serving layer (`serving/disagg.py`) runs on its trunk
     pool and caches per frame digest; `make_head_fn` scores windows from
-    the result.  `megakernel` routes as in `_trunk_quad`."""
+    the result."""
     be = B.get_backend(backend)
     _check_saturation(be)
 
     def run(params, frames):
         p = be.prepare_params(params)
-        return _trunk_quad(be, p, frames, megakernel)
+        return _trunk_quad(be, p, frames)
 
     return jax.jit(run)
 
@@ -366,24 +360,33 @@ runtime.register_reset_hook(make_trunk_fn.cache_clear)
 runtime.register_reset_hook(make_head_fn.cache_clear)
 
 
-def sweep_feature_maps(params: Any, frame: np.ndarray | jnp.ndarray, *,
-                       backend: str | B.Backend = "ref",
-                       megakernel: bool | None = None):
-    """The level-2 role-map quad for one (H,W[,1]) frame: a dict of
-    (H/4, W/4) pooled feature maps {"interior", "last_row", "last_col",
-    "corner"} in the backend's native domain (Qm.n int32 words for the
-    fixed substrates).  This is the sweep trunk without the dense head —
-    what the golden vectors freeze.  `megakernel` as in `_trunk_quad`
-    (False pins the composed per-stage path; the golden generators use it
-    so frozen vectors keep pinning the decomposition itself)."""
+def _feature_maps(trunk, params: Any, frame, backend: str | B.Backend):
     be = B.get_backend(backend)
     _check_saturation(be)
     f = jnp.asarray(np.asarray(frame, np.float32))
     if f.ndim == 2:
         f = f[..., None]
-    quad = _trunk_quad(be, be.prepare_params(params), f[None], megakernel)
+    quad = trunk(be, be.prepare_params(params), f[None])
     names = ("interior", "last_row", "last_col", "corner")
     return {n: np.asarray(_squeeze_map(m)) for n, m in zip(names, quad)}
+
+
+def sweep_feature_maps(params: Any, frame: np.ndarray | jnp.ndarray, *,
+                       backend: str | B.Backend = "ref"):
+    """The level-2 role-map quad for one (H,W[,1]) frame: a dict of
+    (H/4, W/4) pooled feature maps {"interior", "last_row", "last_col",
+    "corner"} in the backend's native domain (Qm.n int32 words for the
+    fixed substrates).  This is the sweep trunk, on the backend's route,
+    without the dense head — what the golden vectors freeze."""
+    return _feature_maps(_trunk_quad, params, frame, backend)
+
+
+def composed_feature_maps(params: Any, frame: np.ndarray | jnp.ndarray, *,
+                          backend: str | B.Backend = "ref"):
+    """`sweep_feature_maps` through `composed_trunk_quad` on every
+    backend: the per-stage decomposition's own words, which the one-launch
+    trunk must equal."""
+    return _feature_maps(composed_trunk_quad, params, frame, backend)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -395,19 +398,12 @@ class FcnSweep(Tiler):
     the host tiler's 14 because sweep windows are nearly free.  `extract`
     returns the frame itself as a (1,H,W,1) "tile" batch (the mass gate
     computes per-window means from it), and `score` runs the jitted sweep:
-    one device call per frame on any registered backend.
-
-    `megakernel` selects the trunk implementation inside that call:
-    None (default) uses the backend's one-launch `frame_trunk` megakernel
-    where it exists (the fixed substrates) and the composed role-map
-    cascade elsewhere; False forces the composed cascade everywhere (the
-    word-exactness baselines pin against this); True requires the
-    megakernel and raises on backends without one.  All three produce
-    identical words on the fixed substrates — the knob changes launches
-    per frame, not scores.
+    one device call per frame on any registered backend.  Inside that
+    call the trunk is the backend's one-launch `frame_trunk` where it has
+    one (the fixed substrates) and the composed role-map cascade
+    elsewhere; both give the same words.
     """
     stride: int = 8
-    megakernel: bool | None = None
     sweep: ClassVar[bool] = True
 
     def __post_init__(self):
@@ -464,7 +460,7 @@ class FcnSweep(Tiler):
                         f"{frames.shape[0]}")
                 H, W = frames.shape[1], frames.shape[2]
                 pos = tuple(self.positions((H, W)))
-                fn = _sweep_fn(be, (H, W), self.patch, pos, self.megakernel)
+                fn = _sweep_fn(be, (H, W), self.patch, pos)
             with T.region("sweep.upload", parent=top):
                 x = jnp.asarray(frames)
             with T.region("sweep.dispatch", parent=top):

@@ -49,7 +49,7 @@ from __future__ import annotations
 
 import dataclasses
 import time
-from typing import Callable, Sequence
+from typing import Callable
 
 import numpy as np
 
@@ -256,11 +256,3 @@ def arrival_cv(gen: LoadGen) -> float:
     if gaps.size < 2 or gaps.mean() == 0:
         return 0.0
     return float(gaps.std() / gaps.mean())
-
-
-def sweep_processes(rate_qps: float, *, n_requests: int, n_streams: int = 4,
-                    seed: int = 0) -> "Sequence[LoadGen]":
-    """One LoadGen per arrival process at the same offered load — the
-    goodput table's row axis."""
-    return [LoadGen(process=p, rate_qps=rate_qps, n_requests=n_requests,
-                    n_streams=n_streams, seed=seed) for p in PROCESSES]

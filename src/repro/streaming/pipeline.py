@@ -38,7 +38,7 @@ waits (`stream_queue_wait_seconds{stage}`: from `_admit` to the stage's
 process-wide metrics registry as bounded histograms / counters (memory
 O(1) in clip length).  The tile and aggregate stages run inside the
 profiler regions `pipeline.tile` / `pipeline.aggregate`.  With tracing
-enabled (`obs.trace.enable()`, or `--trace` on the benchmarks) every frame
+enabled (`obs.trace.enable()`, or `--trace` on `examples/stream_demo.py`) every frame
 carries a root span `frame-<index>` with pipeline.tile/infer/
 pipeline.aggregate child spans and EXACTLY one terminal status — "served"
 or "dropped:<stage>/<reason>" — matching the drop ledger, so a shed frame

@@ -1,6 +1,6 @@
 # NOTE: no XLA_FLAGS here on purpose — tests run on the 1 real CPU device.
-# Only launch/dryrun.py and analysis/run_roofline.py request 512 placeholder
-# devices, in their own processes.
+# Only launch/dryrun.py requests 512 placeholder devices, in its own
+# process.
 import numpy as np
 import pytest
 

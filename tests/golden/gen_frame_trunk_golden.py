@@ -14,8 +14,8 @@ per format and fails loudly on any disagreement:
   * the one-launch megakernel on the emulated "fixed" backend vs on
     "fixed_pallas" (same kernel, both substrate plumbings);
   * the megakernel vs the composed per-stage FcnSweep cascade
-    (megakernel=False — the decomposition the frozen sweep_golden.json
-    already pins);
+    (`composed_feature_maps` — the decomposition the frozen
+    sweep_golden.json already pins);
   * the megakernel vs the untiled numpy int64 oracle
     (kernels/frame_trunk/ref.py), which knows nothing about tiles, halos,
     or window offsets.
@@ -29,13 +29,15 @@ from __future__ import annotations
 import json
 import pathlib
 
+import jax
 import numpy as np
 from gen_sweep_golden import decode_inputs, golden_inputs
 
 from repro.core import backends as B
 from repro.core import fixed_point as fxp
 from repro.kernels.frame_trunk.ref import frame_trunk_quad_ref
-from repro.streaming.fcn_sweep import sweep_feature_maps
+from repro.streaming.fcn_sweep import (composed_feature_maps,
+                                       sweep_feature_maps)
 
 MAPS = ("interior", "last_row", "last_col", "corner")
 FORMATS = {"q16_16": fxp.Q16_16, "q8_8": fxp.Q8_8}
@@ -61,14 +63,14 @@ def main() -> None:
     for fmt, cfg in FORMATS.items():
         be = B.FixedBackend(name=f"fixed_{fmt}", cfg=cfg)
         bp = B.FixedPallasBackend(name=f"fixed_pallas_{fmt}", cfg=cfg)
-        mega = sweep_feature_maps(params, pixels, backend=be,
-                                  megakernel=True)
-        mega_p = sweep_feature_maps(params, pixels, backend=bp,
-                                    megakernel=True)
-        comp = sweep_feature_maps(params, pixels, backend=be,
-                                  megakernel=False)
-
         p = be.prepare_params(params)
+        if jax.eval_shape(lambda f: be.frame_trunk(f, p),
+                          pixels[None]) is None:
+            raise SystemExit(f"{fmt}: the one-launch trunk did not apply")
+        mega = sweep_feature_maps(params, pixels, backend=be)
+        mega_p = sweep_feature_maps(params, pixels, backend=bp)
+        comp = composed_feature_maps(params, pixels, backend=be)
+
         x = np.asarray(be.ingest(pixels[None]))
         oracle = frame_trunk_quad_ref(x[0], np.asarray(p["conv1"]["w"]),
                                       np.asarray(p["conv1"]["b"]),

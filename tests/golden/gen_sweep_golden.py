@@ -35,7 +35,7 @@ import numpy as np
 
 from repro.core import fixed_point as fxp
 from repro.core import smallnet
-from repro.streaming.fcn_sweep import FcnSweep, sweep_feature_maps
+from repro.streaming.fcn_sweep import FcnSweep, composed_feature_maps
 from repro.streaming.tiler import Tiler
 
 STRIDE = 8
@@ -67,18 +67,17 @@ def main() -> None:
     params, pixels = decode_inputs(inputs)
 
     maps = {}
-    # megakernel=False pins the COMPOSED per-stage decomposition itself —
-    # the one-launch frame_trunk route has its own frozen vectors
+    # the maps pin the COMPOSED per-stage decomposition itself — the
+    # one-launch frame_trunk route has its own frozen vectors
     # (frame_trunk_golden.json), so each route is pinned independently
-    by_backend = {b: sweep_feature_maps(params, pixels, backend=b,
-                                        megakernel=False)
+    by_backend = {b: composed_feature_maps(params, pixels, backend=b)
                   for b in ("fixed", "fixed_pallas")}
     for name in MAPS:
         maps[name] = _check_equal(f"map/{name}",
                                   by_backend["fixed"][name],
                                   by_backend["fixed_pallas"][name]).tolist()
 
-    sweep = FcnSweep(stride=STRIDE, megakernel=False)
+    sweep = FcnSweep(stride=STRIDE)
     fb, pos = sweep.extract(pixels)
     scores = _check_equal("scores",
                           sweep.score(params, fb, backend="fixed"),

@@ -1,5 +1,9 @@
 """Backend dispatch: registry coverage + cross-backend parity on the one
-network graph (paper: one datapath, many substrates)."""
+network graph (paper: one datapath, many substrates).
+
+Every parity test runs twice: on `init_params` (zero biases) and on
+`seeded_params` (every leaf perturbed), so bias handling cannot drift
+unseen."""
 import dataclasses
 
 import jax
@@ -14,9 +18,15 @@ from repro.core import smallnet
 REQUIRED = {"ref", "plan", "pallas", "fixed", "fixed_pallas", "int8"}
 
 
+@pytest.fixture(scope="module", params=("init", "perturbed"))
+def case(request):
+    return request.param
+
+
 @pytest.fixture(scope="module")
-def setup(rng):
-    params = smallnet.init_params(jax.random.key(1))
+def setup(case, rng):
+    params = (smallnet.init_params(jax.random.key(1)) if case == "init"
+              else smallnet.seeded_params())
     x = jnp.asarray(rng.uniform(0.0, 1.0, (6, 28, 28, 1)), jnp.float32)
     return params, x
 
@@ -153,12 +163,17 @@ def test_fused_conv_act_pool_hook_matches_composition(setup):
                                       err_msg=name)
 
 
-def test_int8_matches_ref_within_ptq_tolerance(setup):
+def test_int8_matches_ref_within_ptq_tolerance(setup, case):
     params, x = setup
     got = smallnet.apply(params, x, backend="int8")
     want = smallnet.apply(params, x, backend="ref")
+    err = float(jnp.abs(got - want).max())
     # int8 PTQ + PLAN sigmoid: scores move a little, ranking mostly survives
-    assert float(jnp.abs(got - want).max()) < 0.08
+    assert err < 0.08
+    if case == "perturbed":
+        # the untrained perturbed weights leave the ten scores near-tied,
+        # so the ranking is noise there; only the score error is bounded
+        return
     agree = float(jnp.mean(smallnet.predict(got) == smallnet.predict(want)))
     assert agree >= 0.5
 

@@ -45,7 +45,7 @@ def clip():
 
 def _key(i: int) -> FeatureMapKey:
     return FeatureMapKey(digest=f"k{i}", backend="fixed", cfg="q16.16",
-                         megakernel=None, interpret=True)
+                         interpret=True)
 
 
 def _quad(i: int):
@@ -178,11 +178,10 @@ def test_feature_key_separates_every_word_axis(clip):
     from repro.core import backends as B
     px = clip[0].pixels[None]
     fixed, ref = B.get_backend("fixed"), B.get_backend("ref")
-    k = feature_key(px, fixed, None)
-    assert k != feature_key(px, ref, None)             # backend axis
-    assert k != feature_key(px, fixed, True)           # megakernel route
-    assert k != feature_key(clip[1].pixels[None], fixed, None)  # pixels
-    assert k == feature_key(np.array(px), fixed, None)  # content, not id
+    k = feature_key(px, fixed)
+    assert k != feature_key(px, ref)                   # backend axis
+    assert k != feature_key(clip[1].pixels[None], fixed)  # pixels
+    assert k == feature_key(np.array(px), fixed)       # content, not id
 
 
 def test_frame_digest_covers_shape_and_dtype():

@@ -7,18 +7,20 @@ independent routes to the same int32 words get pinned pairwise:
   * the megakernel vs the untiled numpy int64 oracle (ref.py) on small
     random-word frames across tilings — interior, frame border, AND tile
     seams, in both Q16.16 and Q8.8;
-  * the megakernel vs the composed per-stage FcnSweep cascade on the real
-    112x112 streaming frame and on a 512x512 frame (where `choose_tile`
-    splits 512x256 x2, so the seam path runs at acceptance scale) on both
-    fixed substrates;
-  * the end-to-end sweep scores (megakernel route vs composed route) and
-    the `conv_trunk` fast path vs the plain per-stage trunk.
+  * the megakernel vs the composed per-stage FcnSweep cascade
+    (`composed_trunk_quad`, the reference) on the real 112x112 streaming
+    frame and on a 512x512 frame (where `choose_tile` splits 512x256 x2,
+    so the seam path runs at acceptance scale) on both fixed substrates;
+  * the end-to-end sweep scores (the backend's route vs the sweep head
+    over the composed cascade) and the `conv_trunk` fast path vs the
+    plain per-stage trunk.
 
 Launch topology is asserted too: the megakernel trunk must trace to exactly
 ONE `pallas_call`, the composed fixed_pallas cascade to many.
 """
 import numpy as np
 import pytest
+import jax
 import jax.numpy as jnp
 
 from repro.analysis.launches import count_pallas_launches
@@ -30,7 +32,8 @@ from repro.kernels.frame_trunk import choose_tile, frame_trunk_quad
 from repro.kernels.frame_trunk import ops as ft_ops
 from repro.kernels.frame_trunk.ref import frame_trunk_quad_ref
 from repro.streaming import fcn_sweep as fs
-from repro.streaming.fcn_sweep import FcnSweep, sweep_feature_maps
+from repro.streaming.fcn_sweep import (FcnSweep, composed_feature_maps,
+                                       sweep_feature_maps)
 from repro.streaming.sources import SyntheticVideoSource
 
 FIXED_BACKENDS = ("fixed", "fixed_pallas")
@@ -54,6 +57,30 @@ def _rand_trunk_inputs(rng, shape, cfg):
     w2 = random_words(rng, (4,), cfg)
     b2 = random_words(rng, (1,), cfg)
     return x, w1, b1, w2, b2
+
+
+def _composed_scores(params, frame, backend):
+    """Window scores of `FcnSweep().score` with the trunk on the composed
+    cascade: the sweep's own head over `composed_trunk_quad`."""
+    sweep = FcnSweep()
+    fb, pos = sweep.extract(frame)
+    be = B.get_backend(backend)
+    quad = jax.jit(lambda params, x: fs.composed_trunk_quad(
+        be, be.prepare_params(params), x))(params, jnp.asarray(fb))
+    head = fs.make_head_fn(backend, sweep.patch, tuple(pos))
+    return np.asarray(head(params, quad))
+
+
+def _assert_one_launch(params, pixels, backend):
+    """The backend takes its one-launch `frame_trunk` for this frame
+    (traced, not run), so a comparison with the composed cascade is not
+    the cascade against itself."""
+    be = B.get_backend(backend)
+    p = be.prepare_params(params)
+    frames = jnp.asarray(np.asarray(pixels, np.float32).reshape(
+        1, *np.shape(pixels)[:2], 1))
+    assert jax.eval_shape(lambda f: be.frame_trunk(f, p),
+                          frames) is not None, f"{be.name}: no one-launch trunk"
 
 
 def _assert_words(got, want, what):
@@ -120,19 +147,19 @@ def test_megakernel_rejects_bad_geometry():
 
 @pytest.mark.parametrize("backend", FIXED_BACKENDS)
 def test_sweep_maps_megakernel_vs_composed_112(params, frame112, backend):
-    mega = sweep_feature_maps(params, frame112.pixels, backend=backend,
-                              megakernel=True)
-    comp = sweep_feature_maps(params, frame112.pixels, backend=backend,
-                              megakernel=False)
+    _assert_one_launch(params, frame112.pixels, backend)
+    mega = sweep_feature_maps(params, frame112.pixels, backend=backend)
+    comp = composed_feature_maps(params, frame112.pixels, backend=backend)
     for name in ("interior", "last_row", "last_col", "corner"):
         _assert_words(mega[name], comp[name], f"{backend}/112/{name}")
 
 
 @pytest.mark.parametrize("backend", FIXED_BACKENDS)
 def test_sweep_scores_megakernel_vs_composed_112(params, frame112, backend):
+    _assert_one_launch(params, frame112.pixels, backend)
     fb, pos = FcnSweep().extract(frame112)
-    got = FcnSweep(megakernel=True).score(params, fb, backend=backend)
-    want = FcnSweep(megakernel=False).score(params, fb, backend=backend)
+    got = FcnSweep().score(params, fb, backend=backend)
+    want = _composed_scores(params, frame112, backend)
     _assert_words(got, want, f"{backend}/scores")
 
 
@@ -143,10 +170,9 @@ def test_sweep_maps_megakernel_vs_composed_512(params, backend):
     assert choose_tile(512, 512) != (512, 512)  # must genuinely tile
     rng = np.random.default_rng(512)
     frame = rng.random((512, 512), np.float32)
-    mega = sweep_feature_maps(params, frame, backend=backend,
-                              megakernel=True)
-    comp = sweep_feature_maps(params, frame, backend=backend,
-                              megakernel=False)
+    _assert_one_launch(params, frame, backend)
+    mega = sweep_feature_maps(params, frame, backend=backend)
+    comp = composed_feature_maps(params, frame, backend=backend)
     for name in ("interior", "last_row", "last_col", "corner"):
         _assert_words(mega[name], comp[name], f"{backend}/512/{name}")
 
@@ -159,16 +185,11 @@ def test_sweep_megakernel_through_forced_small_tiles(params, frame112,
     equality would otherwise reuse the unforced program)."""
     monkeypatch.setattr(ft_ops, "choose_tile", lambda H, W, **kw: (28, 28))
     be = B.FixedBackend(name="fixed_seamtest")
+    _assert_one_launch(params, frame112.pixels, be)
     fb, pos = FcnSweep().extract(frame112)
-    got = FcnSweep(megakernel=True).score(params, fb, backend=be)
-    want = FcnSweep(megakernel=False).score(params, fb, backend="fixed")
+    got = FcnSweep().score(params, fb, backend=be)
+    want = _composed_scores(params, frame112, "fixed")
     _assert_words(got, want, "forced-28x28-tiles/scores")
-
-
-def test_megakernel_required_raises_on_ref(params, frame112):
-    fb, pos = FcnSweep().extract(frame112)
-    with pytest.raises(NotImplementedError, match="frame_trunk"):
-        FcnSweep(megakernel=True).score(params, fb, backend="ref")
 
 
 # ---------------------------------------------------------------------------
@@ -188,19 +209,24 @@ def test_conv_trunk_fast_path_matches_composed(params, frame112, backend):
 
 
 def test_trunk_launch_topology(params, frame112):
-    """The whole point of the PR: ONE pallas_call per frame on the
-    megakernel route; the composed fixed_pallas cascade stays many."""
+    """ONE pallas_call per frame on the fixed substrates' route; the
+    composed fixed_pallas cascade stays many; ref runs the composed
+    cascade."""
     be = B.get_backend("fixed_pallas")
     p = be.prepare_params(params)
     frame = jnp.asarray(frame112.pixels[None], jnp.float32)
     n_mega = count_pallas_launches(
-        lambda f: fs._trunk_quad(be, p, f, True), frame)
+        lambda f: fs._trunk_quad(be, p, f), frame)
     n_comp = count_pallas_launches(
-        lambda f: fs._trunk_quad(be, p, f, False), frame)
+        lambda f: fs.composed_trunk_quad(be, p, f), frame)
     assert n_mega == 1, f"megakernel trunk traced {n_mega} pallas_calls"
     assert n_comp > 10, f"composed cascade traced only {n_comp}"
     # the emulated backend megakernel route is also exactly one launch
     bef = B.get_backend("fixed")
     pf = bef.prepare_params(params)
     assert count_pallas_launches(
-        lambda f: fs._trunk_quad(bef, pf, f, True), frame) == 1
+        lambda f: fs._trunk_quad(bef, pf, f), frame) == 1
+    # ref has no whole-frame trunk: its route is the composed cascade
+    ber = B.get_backend("ref")
+    pr = ber.prepare_params(params)
+    assert jax.eval_shape(lambda f: ber.frame_trunk(f, pr), frame) is None

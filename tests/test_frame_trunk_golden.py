@@ -15,6 +15,7 @@ Regenerate (only after an INTENTIONAL semantics change) with:
 import json
 import pathlib
 
+import jax
 import numpy as np
 import pytest
 
@@ -56,8 +57,10 @@ def test_golden_covers_both_formats_and_all_maps():
 def test_megakernel_maps_golden(params, frame, fmt, kind):
     cls = B.FixedBackend if kind == "fixed" else B.FixedPallasBackend
     be = cls(name=f"{kind}_{fmt}_golden", cfg=_FORMATS[fmt])
-    maps = sweep_feature_maps(params, frame, backend=be,
-                              megakernel=True)
+    p = be.prepare_params(params)
+    assert jax.eval_shape(lambda f: be.frame_trunk(f, p),
+                          frame[None]) is not None   # the one-launch route
+    maps = sweep_feature_maps(params, frame, backend=be)
     for name in _MAPS:
         np.testing.assert_array_equal(
             np.asarray(maps[name], np.int64),

@@ -89,13 +89,6 @@ def test_megakernel_dma_matches_choose_tile():
     assert wl.bytes_out == 4 * (H // 4) * (W // 4) * 4
 
 
-def test_hlo_crosscheck_agrees_with_model():
-    """XLA's own conv FLOP count on the ref trunk matches the analytic
-    model (the one path HLO can see — Pallas launches are opaque)."""
-    from repro.analysis.run_roofline import _hlo_crosscheck
-    assert _hlo_crosscheck() == []
-
-
 # ---------------------------------------------------------------------------
 # device database
 # ---------------------------------------------------------------------------

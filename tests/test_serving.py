@@ -75,6 +75,7 @@ def test_int8_quanttensor_serving_direct(setup, rng):
 # Vision engine: streaming single-image requests over batched backend steps
 # ---------------------------------------------------------------------------
 
+from repro.core import backends as B
 from repro.core import smallnet
 from repro.launch.mesh import make_serving_mesh
 from repro.serving.router import FleetExhaustedError, ReplicaRouter
@@ -88,10 +89,12 @@ def vision_setup(rng):
     return params, images
 
 
-@pytest.mark.parametrize("backend", ["ref", "pallas"])
+@pytest.mark.parametrize("backend", B.list_backends())
 def test_vision_engine_serves_100_requests(vision_setup, backend):
     """Acceptance: >= 100 queued single-image requests drain through batched
-    jitted steps with per-request latency reported, for two backends."""
+    jitted steps with per-request latency reported, for every registered
+    backend, with no batch shed (a faulted step sheds instead of
+    raising)."""
     params, images = vision_setup
     eng = VisionEngine(params, backend=backend, batch_size=32)
     res = eng.serve(list(images))
@@ -100,6 +103,7 @@ def test_vision_engine_serves_100_requests(vision_setup, backend):
     assert all(r.latency_s > 0 for r in res)
     stats = eng.stats()
     assert stats["n"] == 104
+    assert stats["shed"] == 0 and stats["accounted"]
     assert stats["batches"] == 4                      # ceil(104/32) batched steps
     assert stats["padded_slots"] == 4 * 32 - 104
     assert stats["latency_p95_ms"] >= stats["latency_p50_ms"] > 0

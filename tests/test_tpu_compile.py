@@ -98,10 +98,10 @@ def test_fixed_dense_compiles(one_chip):
 def test_fixed_pallas_sweep_compiles(one_chip):
     """The whole per-frame program of the streaming main path: the
     frame_trunk megakernel plus the fixed_dense window head."""
-    sweep = FcnSweep(stride=8, megakernel=True)
+    sweep = FcnSweep(stride=8)
     pos = tuple(sweep.positions((112, 112)))
     fn = fcn_sweep._sweep_fn(B.get_backend("fixed_pallas"), (112, 112),
-                             sweep.patch, pos, True)
+                             sweep.patch, pos)
     params = jax.eval_shape(smallnet.init_params, jax.random.key(0))
     args = jax.tree_util.tree_map(
         lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip),
