@@ -108,9 +108,8 @@ def phase_compiled(ctx) -> str:
            f"interpret default {runtime.interpret_default()} on "
            f"{ctx['platform']}")
     sweep = FcnSweep(stride=STRIDE)
-    pos = tuple(sweep.positions((112, 112)))
     fn = fcn_sweep._sweep_fn(B.get_backend("fixed_pallas"), (112, 112),
-                             sweep.patch, pos)
+                             sweep.patch, sweep.stride)
     texts = {"sweep": fn.lower(ctx["params"],
                                jnp.zeros((1, 112, 112, 1))).as_text()}
     eng = VisionEngine(ctx["params"], backend="fixed_pallas", batch_size=32,

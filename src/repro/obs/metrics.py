@@ -305,6 +305,11 @@ REGISTRY = Registry()
 AGG_WINDOWS = "aggregate_windows"
 AGG_CANDIDATES = "aggregate_candidates"
 
+# builds of a sweep window geometry (`streaming/fcn_sweep._sweep_geometry`,
+# one increment per cache miss): one per frame shape per process, so a
+# window after warm-up adds none
+SWEEP_LATTICE_BUILDS = "sweep_lattice_builds"
+
 # unique instance labels so N engines / pipelines / routers coexist in the
 # one process-wide registry without clobbering each other's instruments
 _instance_seq = itertools.count()

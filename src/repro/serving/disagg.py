@@ -732,8 +732,8 @@ class DisaggServer:
         sweep = fs.FcnSweep(patch=self.patch, stride=self.stride)
         self.positions = tuple(sweep.positions(self.frame_shape))
         self._trunk_fn = fs.make_trunk_fn(self.backend.name)
-        self._head_fn = fs.make_head_fn(self.backend.name, self.patch,
-                                        self.positions)
+        self._head_fn = fs.make_head_fn(self.backend.name, self.frame_shape,
+                                        self.patch, self.stride)
         self.cache = (cache if cache is not None
                       else FeatureMapCache(capacity=cache_capacity,
                                            ttl_s=cache_ttl_s))

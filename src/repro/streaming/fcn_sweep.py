@@ -50,6 +50,9 @@ Edge/geometry contract (validated loudly, tested in tests/test_fcn_sweep.py):
     `tile_positions` builds): the head reads windows as static slices.
   * `patch` must be a multiple of 4 (the deployed dense head fixes it at
     28: 49 pooled features).
+  * the positions and their lattice are a pure function of (H, W,
+    patch, stride): `_sweep_geometry` checks and builds them once per
+    geometry, and every frame of that shape reads the stored objects.
   * saturating fixed-point configs are rejected (saturation is not
     associative, so the decomposed accumulation could drift); the
     registered `fixed`/`fixed_pallas` backends use the hardware-faithful
@@ -86,6 +89,7 @@ from jax import lax
 from repro.core import backends as B
 from repro.core import runtime
 from repro.core import smallnet
+from repro.obs import metrics as M
 from repro.obs import trace as T
 from repro.streaming.sources import Frame
 from repro.streaming.tiler import Tiler, tile_positions
@@ -243,7 +247,7 @@ def _axis_starts(starts: list[int]) -> tuple[int, int, int, int | None]:
 
 
 def _window_lattice(patch: int,
-                    positions: tuple[tuple[int, int], ...]) -> _Lattice:
+                    positions: Sequence[tuple[int, int]]) -> _Lattice:
     """The static lattice for scoring `positions` from a pooled role-map
     quad.  `positions` must be the row-major product of its distinct row
     and column starts, each an evenly stepped run plus at most one
@@ -257,6 +261,35 @@ def _window_lattice(patch: int,
             f"row and column starts: {len(positions)} positions are not "
             f"{len(ys)} rows x {len(xs)} cols in that order")
     return _Lattice(patch // _POOL, _axis_starts(ys), _axis_starts(xs))
+
+
+class _Geometry(NamedTuple):
+    """One sweep geometry, built once: the window `positions` in
+    `tile_positions` order and the static `lattice` the head reads them
+    through.  `positions` is a plain list shared by every frame of the
+    shape; callers index and iterate it and never mutate it."""
+    positions: list[tuple[int, int]]
+    lattice: _Lattice
+
+
+@functools.lru_cache(maxsize=64)
+def _sweep_geometry(H: int, W: int, patch: int, stride: int) -> _Geometry:
+    """The window geometry of an HxW frame at (patch, stride), a pure
+    function of those four numbers: checks the edge contract, builds the
+    positions and their lattice, and counts the build in the registry
+    counter `sweep_lattice_builds`.  Every later frame of the shape reads
+    the stored objects."""
+    if (H - patch) % _POOL or (W - patch) % _POOL:
+        raise ValueError(
+            f"frame {(H, W)} breaks the sweep edge contract: the "
+            f"edge-clamped last window at (H-{patch}, W-{patch}) must sit "
+            f"on the stride-{_POOL} pooled lattice, i.e. (H - patch) % "
+            f"{_POOL} == 0 on both axes (pad or crop the frame to a "
+            f"multiple of {_POOL})")
+    positions = tile_positions((H, W), patch, stride)
+    geometry = _Geometry(positions, _window_lattice(patch, positions))
+    M.REGISTRY.counter(M.SWEEP_LATTICE_BUILDS).inc()
+    return geometry
 
 
 def _take(m, axis: int, starts: tuple[int, int, int, int | None], off: int):
@@ -302,11 +335,13 @@ def _head_scores(be: B.Backend, p: dict, quad, lattice: _Lattice):
 
 @functools.lru_cache(maxsize=64)
 def _sweep_fn(be: B.Backend, frame_shape: tuple[int, int], patch: int,
-              positions: tuple[tuple[int, int], ...]):
-    """Jitted whole-sweep function for one (backend, geometry): params +
-    (1,H,W,1) float frame -> (n_windows, 10) backend-native scores, ONE
-    device call per frame."""
-    lattice = _window_lattice(patch, positions)
+              stride: int):
+    """Jitted whole-sweep function for one (backend, frame shape, patch,
+    stride): params + (1,H,W,1) float frame -> (n_windows, 10)
+    backend-native scores, ONE device call per frame.  The key is four
+    small values, so a frame's lookup costs the same at any window
+    count; the lattice comes from `_sweep_geometry`."""
+    lattice = _sweep_geometry(*frame_shape, patch, stride).lattice
 
     def run(params, frame):
         p = be.prepare_params(params)
@@ -335,16 +370,18 @@ def make_trunk_fn(backend: str):
 
 
 @functools.lru_cache(maxsize=64)
-def make_head_fn(backend: str, patch: int,
-                 positions: tuple[tuple[int, int], ...]):
+def make_head_fn(backend: str, frame_shape: tuple[int, int], patch: int,
+                 stride: int):
     """Jitted HEAD half of the sweep: (params, role-map quad) -> (Nw, 10)
-    backend-native window scores for a fixed window lattice.  Runs the
-    SAME traced window slices + dense head as the monolithic `_sweep_fn`
-    (`_head_scores`), so head-pool scores from a cached feature quad are
-    int32 word-exact vs the one-call sweep on the fixed substrates."""
+    backend-native window scores for the window lattice of an HxW frame
+    at (patch, stride), taken from the same `_sweep_geometry` as
+    `_sweep_fn`.  Runs the SAME traced window slices + dense head as the
+    monolithic `_sweep_fn` (`_head_scores`), so head-pool scores from a
+    cached feature quad are int32 word-exact vs the one-call sweep on
+    the fixed substrates."""
     be = B.get_backend(backend)
     _check_saturation(be)
-    lattice = _window_lattice(patch, positions)
+    lattice = _sweep_geometry(*frame_shape, patch, stride).lattice
 
     def run(params, quad):
         p = be.prepare_params(params)
@@ -358,6 +395,7 @@ def make_head_fn(backend: str, patch: int,
 runtime.register_reset_hook(_sweep_fn.cache_clear)
 runtime.register_reset_hook(make_trunk_fn.cache_clear)
 runtime.register_reset_hook(make_head_fn.cache_clear)
+runtime.register_reset_hook(_sweep_geometry.cache_clear)
 
 
 def _feature_maps(trunk, params: Any, frame, backend: str | B.Backend):
@@ -418,20 +456,17 @@ class FcnSweep(Tiler):
                 f"{self.stride})")
 
     def positions(self, frame_shape: tuple[int, int]) -> list[tuple[int, int]]:
+        """`tile_positions` for the frame, checked against the edge
+        contract.  The list is built once per (shape, patch, stride) and
+        shared by every frame of that shape: read it, never mutate it."""
         H, W = frame_shape
-        if (H - self.patch) % _POOL or (W - self.patch) % _POOL:
-            raise ValueError(
-                f"frame {frame_shape} breaks the sweep edge contract: the "
-                f"edge-clamped last window at (H-{self.patch}, W-"
-                f"{self.patch}) must sit on the stride-{_POOL} pooled "
-                f"lattice, i.e. (H - patch) % {_POOL} == 0 on both axes "
-                f"(pad or crop the frame to a multiple of {_POOL})")
-        return tile_positions(frame_shape, self.patch, self.stride)
+        return _sweep_geometry(H, W, self.patch, self.stride).positions
 
     def extract(self, frame: Frame | np.ndarray) -> tuple[np.ndarray,
                                                           list[tuple[int, int]]]:
         """Frame -> ((1, H, W, 1) float32 frame batch, window positions).
-        No host-side patch materialization — that is the whole point."""
+        No host-side patch materialization — that is the whole point — and
+        the positions are the shape's stored list (`positions`)."""
         px = frame.pixels if isinstance(frame, Frame) else np.asarray(frame)
         if px.ndim == 2:
             px = px[..., None]
@@ -443,7 +478,9 @@ class FcnSweep(Tiler):
         """One jitted full-frame trunk pass + windowed dense head:
         (1, H, W, 1) frame -> (n_windows, 10) backend-native scores, in
         `positions` order.  Profiler regions: `sweep.score`, holding
-        `sweep.prepare` (frame array, positions, program lookup),
+        `sweep.prepare` (backend lookup, saturation check, frame array,
+        and the program lookup keyed on (backend, shape, patch, stride),
+        which builds no per-window sequence),
         `sweep.upload`, `sweep.dispatch` (the call until it returns) and
         `sweep.fetch` (the wait for the scores and their copy back)."""
         with T.region("sweep.score") as top:
@@ -458,9 +495,8 @@ class FcnSweep(Tiler):
                         f"FcnSweep.score takes one frame per call (the sweep "
                         f"is a per-frame device program), got batch "
                         f"{frames.shape[0]}")
-                H, W = frames.shape[1], frames.shape[2]
-                pos = tuple(self.positions((H, W)))
-                fn = _sweep_fn(be, (H, W), self.patch, pos)
+                fn = _sweep_fn(be, frames.shape[1:3], self.patch,
+                               self.stride)
             with T.region("sweep.upload", parent=top):
                 x = jnp.asarray(frames)
             with T.region("sweep.dispatch", parent=top):

@@ -17,7 +17,9 @@ import pytest
 
 from repro.core import backends as B
 from repro.core import fixed_point as fxp
+from repro.core import runtime
 from repro.core import smallnet
+from repro.obs import metrics as M
 from repro.serving.vision_engine import VisionEngine
 from repro.streaming import fcn_sweep as fs
 from repro.streaming.fcn_sweep import FcnSweep, sweep_feature_maps
@@ -72,6 +74,68 @@ def test_sweep_positions_match_tiler_lattice():
     for stride in (4, 8, 12, 28):
         assert FcnSweep(stride=stride).positions((112, 112)) == \
             tile_positions((112, 112), 28, stride)
+
+
+def _geometry_builds() -> int:
+    return M.REGISTRY.counter(M.SWEEP_LATTICE_BUILDS).value
+
+
+def _noise_frame(shape):
+    return np.random.default_rng(shape[1]).random(shape + (1,)).astype(
+        np.float32)
+
+
+@pytest.mark.parametrize("shape", ((112, 112), (120, 160)))
+def test_geometry_built_once_per_frame_shape(params, monkeypatch, shape):
+    """Two extract + score calls on frames of one shape build the window
+    geometry once, and the second builds no position list at all; a
+    second shape builds its own."""
+    fs._sweep_geometry.cache_clear()
+    sweep = FcnSweep(stride=8)
+    before = _geometry_builds()
+    fb, pos = sweep.extract(_noise_frame(shape))
+    first = sweep.score(params, fb, backend="fixed")
+    assert _geometry_builds() == before + 1
+
+    def rebuilt(*args):
+        raise AssertionError("window positions rebuilt for a known shape")
+
+    with monkeypatch.context() as m:
+        m.setattr(fs, "tile_positions", rebuilt)
+        fb2, pos2 = sweep.extract(_noise_frame(shape))
+        second = sweep.score(params, fb2, backend="fixed")
+    assert pos2 is pos
+    assert second.shape == first.shape == (len(pos), 10)
+    assert _geometry_builds() == before + 1
+    sweep.extract(_noise_frame((shape[0] + 8, shape[1])))
+    assert _geometry_builds() == before + 2
+
+
+@pytest.mark.parametrize("stride", (4, 8, 12))
+@pytest.mark.parametrize("shape", ((112, 112), (120, 160)))
+def test_stored_positions_equal_tile_positions(shape, stride):
+    """The stored positions are `tile_positions`' list, the same object
+    for every caller of the shape, and the lattice reads them."""
+    geometry = fs._sweep_geometry(*shape, 28, stride)
+    want = tile_positions(shape, 28, stride)
+    assert geometry.positions == want
+    assert FcnSweep(stride=stride).positions(shape) is geometry.positions
+    assert geometry.lattice == fs._window_lattice(28, tuple(want))
+
+
+def test_reset_hooks_rebuild_geometry_with_the_same_words(params):
+    """The runtime's reset hooks clear the geometry cache with the
+    program caches; the rebuilt program scores the same words."""
+    assert fs._sweep_geometry.cache_clear in runtime._RESET_HOOKS
+    sweep = FcnSweep(stride=8)
+    fb, _ = sweep.extract(_noise_frame((120, 160)))
+    before = sweep.score(params, fb, backend="fixed")
+    builds = _geometry_builds()
+    for hook in runtime._RESET_HOOKS:
+        hook()
+    after = sweep.score(params, fb, backend="fixed")
+    assert _geometry_builds() == builds + 1
+    np.testing.assert_array_equal(after, before)
 
 
 def test_edge_contract_fails_loudly():
@@ -335,7 +399,7 @@ def test_head_program_has_no_gather(params):
     reference's own lowering shows the check can see one)."""
     pos = tuple(tile_positions((112, 112), 28, 8))
     quad = _random_quad((112, 112), seed=0)
-    head = fs.make_head_fn("fixed_pallas", 28, pos)
+    head = fs.make_head_fn("fixed_pallas", (112, 112), 28, 8)
     assert "gather" not in head.lower(params, quad).as_text()
     assert "gather" in jax.jit(_gather_features, static_argnums=1).lower(
         quad, pos).as_text()

@@ -63,11 +63,11 @@ def _composed_scores(params, frame, backend):
     """Window scores of `FcnSweep().score` with the trunk on the composed
     cascade: the sweep's own head over `composed_trunk_quad`."""
     sweep = FcnSweep()
-    fb, pos = sweep.extract(frame)
+    fb, _ = sweep.extract(frame)
     be = B.get_backend(backend)
     quad = jax.jit(lambda params, x: fs.composed_trunk_quad(
         be, be.prepare_params(params), x))(params, jnp.asarray(fb))
-    head = fs.make_head_fn(backend, sweep.patch, tuple(pos))
+    head = fs.make_head_fn(backend, fb.shape[1:3], sweep.patch, sweep.stride)
     return np.asarray(head(params, quad))
 
 
